@@ -26,9 +26,10 @@ against.  Modules:
                          HLO-measured op counts (digital substrates) or
                          array physics (analogue substrates)
   fleet_sharded        — multi-device fleet serving via launch.fleet_serving:
-                         single-device baseline vs sharded rollout on the
-                         trivial mesh, plus per-device scaling rows from a
-                         virtual multi-device subprocess
+                         single-device baseline vs sharded rollout over
+                         this host's devices, plus per-device scaling rows
+                         (the real chips on a TPU host, a virtual
+                         multi-device CPU subprocess elsewhere)
   train_throughput     — scan-compiled fit() engine vs per-step baseline,
                          plus digital-adjoint vs fused-VJP training steps
                          and the bf16_f32acc training substrate rows
@@ -467,6 +468,29 @@ def bench_fleet_backends():
          f"chunk {plan.time_chunk} x{plan.num_chunks}")
 
 
+def sharded_scaling_rows(backend: str, n: int, horizon: int,
+                         shard_counts) -> list:
+    """``(name, us, derived)`` rows: one Lorenz96 fleet rollout over a
+    twin mesh of each size in ``shard_counts``, on this process's
+    devices."""
+    import jax
+    from repro.launch.mesh import make_twin_mesh
+    from repro.train import recipes
+    fleet = recipes.make_l96_fleet(backend=backend)
+    params = fleet.twin.init(jax.random.PRNGKey(0))
+    ts = recipes.l96_fleet_ts(horizon=horizon)
+    y0s = next(recipes.l96_fleet_requests(fleet_size=n))
+    rows = []
+    for shards in shard_counts:
+        mesh = make_twin_mesh(shards)
+        fn = jax.jit(lambda p, y, mesh=mesh: fleet.rollout_batch(
+            p, y, ts, mesh=mesh))
+        us = _timeit(fn, params, y0s, best=True)
+        rows.append((f"fleet_sharded/{backend}/shards{shards}/n{n}", us,
+                     f"{n * horizon / (us * 1e-6):.0f} twin-steps/s"))
+    return rows
+
+
 def bench_fleet_sharded():
     """Multi-device fleet serving (repro.launch.fleet_serving).
 
@@ -474,10 +498,15 @@ def bench_fleet_sharded():
     the sharded path on the trivial mesh of this host — same program,
     plus the shard_map wrapper, so the delta is pure sharding overhead
     (and the derived field carries the parity error, which must be 0).
-    The per-device scaling rows run in a subprocess with virtual host
-    devices (``--xla_force_host_platform_device_count``): on CPU the
-    virtual devices share the same cores, so these rows validate the
-    scaling *mechanism* and become real speedups on multi-chip hosts.
+
+    The per-device scaling rows run over the real devices on a TPU host,
+    in this process (a process that has touched JAX holds the chips, so
+    a child could not get them).  On CPU they run in a child process
+    pinned to the CPU with virtual host devices
+    (``--xla_force_host_platform_device_count``, which must be set before
+    JAX starts): the virtual devices share the same cores, so those rows
+    validate the scaling *mechanism* only.  A failed child fails the
+    module.
     """
     import subprocess
     import textwrap
@@ -507,52 +536,39 @@ def bench_fleet_sharded():
     us_sharded = _timeit(sharded, params, y0s)
     emit(f"fleet_sharded/fused/single_device/n{n}", us_single,
          f"{n * horizon / (us_single * 1e-6):.0f} twin-steps/s")
-    emit(f"fleet_sharded/fused/sharded_1dev/n{n}", us_sharded,
+    emit(f"fleet_sharded/fused/sharded_{len(mesh.devices.flat)}dev/n{n}",
+         us_sharded,
          f"{n * horizon / (us_sharded * 1e-6):.0f} twin-steps/s "
          f"parity_max_err {gap:.1e}")
 
-    # per-device scaling: virtual 4-device mesh in a subprocess (XLA_FLAGS
-    # must be set before jax initialises)
+    if jax.default_backend() != "cpu":
+        counts = [s for s in (1, 2, 4) if s <= jax.device_count()]
+        for row in sharded_scaling_rows("fused_pallas", n, horizon, counts):
+            emit(*row)
+        return
+
     code = textwrap.dedent(f"""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-        import time
-        import jax, jax.numpy as jnp
-        from repro.launch.mesh import make_twin_mesh
-        from repro.train import recipes
-        fleet = recipes.make_l96_fleet(backend="digital")
-        params = fleet.twin.init(jax.random.PRNGKey(0))
-        ts = recipes.l96_fleet_ts(horizon={horizon})
-        y0s = next(recipes.l96_fleet_requests(fleet_size={n}))
-        for shards in [1, 2, 4]:
-            mesh = make_twin_mesh(shards)
-            fn = jax.jit(lambda p, y: fleet.rollout_batch(p, y, ts,
-                                                          mesh=mesh))
-            jax.block_until_ready(fn(params, y0s))
-            times = []
-            for _ in range(3):
-                t0 = time.time()
-                jax.block_until_ready(fn(params, y0s))
-                times.append(time.time() - t0)
-            us = min(times) * 1e6
-            rate = {n} * {horizon} / (us * 1e-6)
-            print(f"RESULT,fleet_sharded/digital/shards{{shards}}/"
-                  f"n{n},{{us:.3f}},{{rate:.0f}} twin-steps/s")
+        from benchmarks.run import sharded_scaling_rows
+        for name, us, derived in sharded_scaling_rows(
+                "digital", {n}, {horizon}, [1, 2, 4]):
+            print(f"RESULT,{{name}},{{us:.3f}},{{derived}}")
     """)
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True,
-                       env={**os.environ,
-                            "PYTHONPATH": os.path.join(
-                                os.path.dirname(__file__), "..", "src")})
-    ok = False
-    for line in r.stdout.splitlines():
-        if line.startswith("RESULT,"):
-            _, name, us, derived = line.split(",", 3)
-            emit(name, float(us), derived)
-            ok = True
-    if not ok:
-        print(f"  (virtual multi-device subprocess failed)\n"
-              f"{r.stderr[-2000:]}")
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    r = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=root, env={**os.environ, "JAX_PLATFORMS": "cpu",
+                       "PYTHONPATH": os.pathsep.join(
+                           [root, os.path.join(root, "src")])})
+    results = [line.split(",", 3)[1:] for line in r.stdout.splitlines()
+               if line.startswith("RESULT,")]
+    if r.returncode != 0 or len(results) != 3:
+        raise RuntimeError(
+            f"bench_fleet_sharded: virtual multi-device subprocess failed "
+            f"(exit {r.returncode}):\n{r.stderr[-2000:]}")
+    for name, us, derived in results:
+        emit(name, float(us), derived)
 
 
 def bench_train_throughput():
@@ -1159,6 +1175,8 @@ def main() -> None:
     ap.add_argument("--artifact-dir", default=".",
                     help="where BENCH_<module>.json artifacts are written")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     t0 = time.time()
     names = args.only if args.only else list(BENCHES)
     unknown = [n for n in names if n not in BENCHES]

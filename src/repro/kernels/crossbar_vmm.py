@@ -69,6 +69,15 @@ def pad_accumulator_neutral(x: jax.Array, mult: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
+def conductance_f32(g: jax.Array) -> jax.Array:
+    """Widen stored conductances (f32, or uint8 level indices) to f32
+    in-kernel; Mosaic has no direct uint8 -> float cast, so integer
+    levels go through int32."""
+    if jnp.issubdtype(g.dtype, jnp.integer):
+        g = g.astype(jnp.int32)
+    return g.astype(jnp.float32)
+
+
 def _kernel(x_ref, gp_ref, gm_ref, o_ref, acc_ref, *, nk: int, bk: int,
             bn: int, K: int, N: int, g_step: float | None,
             g_min: float, g_max: float, inv_scale: float,
@@ -79,8 +88,8 @@ def _kernel(x_ref, gp_ref, gm_ref, o_ref, acc_ref, *, nk: int, bk: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    gp = gp_ref[...].astype(jnp.float32)
-    gm = gm_ref[...].astype(jnp.float32)
+    gp = conductance_f32(gp_ref[...])
+    gm = conductance_f32(gm_ref[...])
     stuck = stuck_rate > 0.0
     if g_step is not None and (read_noise > 0.0 or stuck):
         # Quantised storage: reconstruct the absolute conductances —

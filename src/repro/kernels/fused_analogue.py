@@ -50,8 +50,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET,
-                                         _chunk_drive, _default_interpret,
+                                         _default_interpret,
+                                         chunked_drive_operand,
                                          plan_time_chunk)
+from repro.kernels.crossbar_vmm import conductance_f32
 from repro.kernels.noise import counter_normal, stuck_cell_masks
 
 #: Static fault parameters the kernel understands (subset optional);
@@ -99,13 +101,13 @@ def _make_kernel(num_layers: int, C: int, dt: float, drive_dim: int,
         if read_noise > 0.0:
             # Stationary absolute conductances; re-noised per evaluation.
             if g_step is not None:
-                gps = [g_min + r[...].astype(jnp.float32) * g_step
+                gps = [g_min + conductance_f32(r[...]) * g_step
                        for r in gp_refs]
-                gms = [g_min + r[...].astype(jnp.float32) * g_step
+                gms = [g_min + conductance_f32(r[...]) * g_step
                        for r in gm_refs]
             else:
-                gps = [r[...].astype(jnp.float32) for r in gp_refs]
-                gms = [r[...].astype(jnp.float32) for r in gm_refs]
+                gps = [conductance_f32(r[...]) for r in gp_refs]
+                gms = [conductance_f32(r[...]) for r in gm_refs]
             if stuck:
                 gps = [apply_stuck(g, li, 0) for li, g in enumerate(gps)]
                 gms = [apply_stuck(g, li, 1) for li, g in enumerate(gms)]
@@ -117,8 +119,8 @@ def _make_kernel(num_layers: int, C: int, dt: float, drive_dim: int,
             # active the quantised pair must be reconstructed first.
             ws, bs = [], []
             for li in range(num_layers):
-                gp_a = gp_refs[li][...].astype(jnp.float32)
-                gm_a = gm_refs[li][...].astype(jnp.float32)
+                gp_a = conductance_f32(gp_refs[li][...])
+                gm_a = conductance_f32(gm_refs[li][...])
                 if stuck:
                     if g_step is not None:
                         gp_a = g_min + gp_a * g_step
@@ -130,7 +132,7 @@ def _make_kernel(num_layers: int, C: int, dt: float, drive_dim: int,
                         g = g * g_step
                 g = g * inv_scales[li]
                 ws.append(g[:-1])        # (K, N) weight rows
-                bs.append(g[-1])         # the constant-1 bias row
+                bs.append(g[-1:])        # (1, N): the constant-1 bias row
         salts_per_step = 4 * num_layers * 2     # stages x layers x pair
         # Hoisted out of the fori_loop body: program_id has no lowering
         # inside a captured loop jaxpr on the interpreter path.
@@ -148,7 +150,7 @@ def _make_kernel(num_layers: int, C: int, dt: float, drive_dim: int,
                 g = (gps[li] * (1.0 + read_noise * ep)
                      - gms[li] * (1.0 + read_noise * em))
                 y = (jnp.dot(x, g[:-1], preferred_element_type=jnp.float32)
-                     + g[-1][None, :]) * inv_scales[li]
+                     + g[-1:]) * inv_scales[li]
             else:
                 y = jnp.dot(x, ws[li],
                             preferred_element_type=jnp.float32) + bs[li]
@@ -308,16 +310,8 @@ def fused_analogue_rollout(
                           int(fa["drift_n0"]), int(step_offset))
 
     grid = (B // bt, NC)
-    if per_tile_drive:
-        u_tm = jnp.transpose(u_half, (1, 0, 2))          # (2T+1, B, du)
-        u_in = _chunk_drive(u_tm, C, NC)                 # (NC, 2C+1, B, du)
-        u_spec = pl.BlockSpec((1, 2 * C + 1, bt, du),
-                              lambda i, j: (j, 0, i, 0))
-    else:
-        u_tm = u_half if du > 0 else jnp.zeros((2 * T + 1, 1), jnp.float32)
-        u_in = _chunk_drive(u_tm, C, NC)                 # (NC, 2C+1, du')
-        u_spec = pl.BlockSpec((1, 2 * C + 1, max(du, 1)),
-                              lambda i, j: (j, 0, 0))
+    u_in, u_spec = chunked_drive_operand(u_half, T, C, NC, bt,
+                                         per_tile_drive, reverse=False)
     in_specs = [
         pl.BlockSpec((bt, D), lambda i, j: (i, 0)),      # y0
         u_spec,                                          # u_chunks

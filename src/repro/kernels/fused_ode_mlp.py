@@ -23,21 +23,27 @@ Block layout per (i, j) cell:
   out      (C, bt, D)         chunk j's slab of the trajectory
   carry    (bt, D)            VMEM scratch, persistent across the grid
 
-VMEM per cell ~= weights + C*bt*D (out slab) + (2C+1)*Du (drive slab)
-+ carry + activations, each term sized by its policy dtype; the horizon
-T no longer has to fit — only one chunk does.  ``time_chunk=None``
+VMEM per cell is counted as the chip lays it out (:func:`vmem_tile_bytes`:
+the last two dims of every buffer padded to the (sublane, 128-lane)
+tile, two buffers per pipelined block): weights + the (C, bt, D) out
+slab + the (2C+1)-row drive slab + carry + activations.  The horizon T
+does not have to fit — only one chunk does.  ``time_chunk=None``
 auto-picks the largest C within ``vmem_budget_bytes``, so weights stay
 resident while arbitrarily long horizons stream chunk-by-chunk through
-HBM.  A ``ValueError`` is now raised only when the weights plus a
-single step genuinely cannot fit.
+HBM.  A ``ValueError`` is raised only when the weights plus a single
+step cannot fit.
 
 Mixed precision: the ``precision`` policy decides the byte width of
-everything that streams through VMEM/HBM.  ``"bf16_f32acc"`` (the TPU
-default) stores weights, drive slabs and trajectory slabs in bfloat16
-— halving HBM traffic and roughly doubling the resident time chunk —
-while every ``jnp.dot`` accumulates at float32 on the MXU and the RK4
-state carry stays float32 in VMEM scratch.  ``"bf16"`` additionally
-carries the state at bfloat16 (the fully reduced substrate, mirroring
+the weights and trajectory slabs.  ``"bf16_f32acc"`` (the TPU default)
+stores weights and trajectory slabs in bfloat16 — halving HBM traffic
+and roughly doubling the resident time chunk — while every ``jnp.dot``
+accumulates at float32 on the MXU and the RK4 state carry stays float32
+in VMEM scratch.  The drive slab stays float32 under every policy: the
+kernel reads it one half-step row at a time, which Mosaic cannot do on
+a bf16 slab (two rows share a sublane), and the step casts the drive to
+the compute dtype before any matmul, so the result is the same as with
+a bf16 slab.  ``"bf16"`` additionally carries the state at bfloat16
+(the fully reduced substrate, mirroring
 the analogue crossbar's precision tolerance); ``"f32"`` is the exact
 float32 path.  In the bf16 policies the carried state is rounded to
 the storage dtype once per chunk boundary, so the chunk-start states
@@ -68,9 +74,9 @@ bitwise parity.
 """
 from __future__ import annotations
 
-import functools
+import math
 import os
-from typing import NamedTuple, Sequence
+from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -79,7 +85,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-DEFAULT_VMEM_BUDGET = 14 * 1024 * 1024   # ~16 MB/core minus headroom
+#: Per-cell VMEM the chunk planners fill.  Mosaic's default scoped-VMEM
+#: limit for one kernel on a TPU v5e is 16 MiB.  The planners count
+#: buffers as the chip lays them out (tiled, two per pipelined block —
+#: :func:`vmem_tile_bytes`) and leave the other 4 MiB to the compiler's
+#: own scratch (spilled loop values, matmul temporaries), so a planned
+#: kernel compiles without raising the limit.
+DEFAULT_VMEM_BUDGET = 12 * 1024 * 1024
+
+#: The drive slab's dtype in HBM and VMEM (see the module docstring).
+DRIVE_DTYPE = jnp.float32
 
 #: Supported precision policies (see the module docstring's error model).
 PRECISIONS = ("f32", "bf16", "bf16_f32acc")
@@ -124,8 +139,9 @@ def resolve_precision(precision: str | None) -> str:
 def precision_dtypes(precision: str):
     """``(store, compute, acc, carry)`` dtypes of a resolved policy.
 
-    store   — weights/biases, drive slabs, trajectory slabs (HBM + the
-              VMEM-resident operand blocks);
+    store   — weights/biases and trajectory slabs (HBM + the
+              VMEM-resident operand blocks; the drive stays at
+              :data:`DRIVE_DTYPE`);
     compute — matmul operand dtype fed to the MXU;
     acc     — ``preferred_element_type`` of every in-kernel ``jnp.dot``;
     carry   — the RK4 integration state in VMEM scratch.
@@ -158,9 +174,38 @@ class ChunkPlan(NamedTuple):
     vmem_bytes: int          # estimated per-cell VMEM footprint
 
 
-def _rk4_activation_bytes(bt: int, D: int, du: int,
-                          weights: Sequence[jax.Array],
-                          acc_itemsize: int) -> int:
+def vmem_tile_bytes(shape: Sequence[int], dtype) -> int:
+    """Bytes one VMEM buffer of ``shape`` occupies on the chip.
+
+    Mosaic tiles the last two dims: the minor dim is padded to 128 lanes
+    and the second-minor to whole sublane groups — 8 rows for 32-bit
+    types, 16 for 16-bit (two rows packed per sublane), 32 for 8-bit.
+    A rank-1 buffer is one such row.  So a (C, bt, 1) slab takes 128x
+    its logical bytes, which a plain ``size * itemsize`` count misses.
+    """
+    itemsize = jnp.dtype(dtype).itemsize
+    shape = tuple(shape)
+    if len(shape) < 2:
+        shape = (1,) * (2 - len(shape)) + shape
+    *lead, rows, cols = shape
+    sublanes = 8 * (4 // itemsize)
+    rows = -(-rows // sublanes) * sublanes
+    cols = -(-cols // 128) * 128
+    return math.prod(lead) * rows * cols * itemsize
+
+
+def drive_block_shape(C: int, bt: int, du: int,
+                      per_tile_drive: bool) -> tuple:
+    """The drive slab's per-cell block: ``(1, 2C+1, bt, Du)`` per-twin,
+    ``(1, 2C+1, max(Du, 1))`` shared (autonomous fields get one zero
+    column)."""
+    if per_tile_drive:
+        return (1, 2 * C + 1, bt, du)
+    return (1, 2 * C + 1, max(du, 1))
+
+
+def _rk4_activation_bytes(bt: int, D: int, weights: Sequence[jax.Array],
+                          acc_dtype) -> int:
     """VMEM slack for the live RK4 temporaries of one step.
 
     Derived from what one ``make_rk4_step`` invocation actually keeps
@@ -169,20 +214,52 @@ def _rk4_activation_bytes(bt: int, D: int, du: int,
       6 · (bt, D)          y, k1..k4 and the perturbed state y + c·k_i
                            (the final combination holds all four k's plus
                            y at once — six state-width buffers)
-      (bt, in_l + out_l)   the widest adjacent (input, output) activation
+      (bt, in_l), (bt, out_l)
+                           the widest adjacent (input, output) activation
                            pair of the MLP — at any moment one layer's
                            input and its dot output coexist; the first
                            layer's input width already includes du + D
                            through w_0.shape[0]
 
-    i.e. ``act = acc_itemsize · bt · (6·D + max_l(in_l + out_l))``.  This
-    replaces the old ``4 · bt · max(du + D, max width) · 6`` magic
-    constant, which over-counted narrow-state MLPs ~3x and under-counted
-    none of the tested shapes.
+    each counted tiled (:func:`vmem_tile_bytes`).
     """
-    del du  # already folded into w_0.shape[0] by the caller's MLP sizes
-    widest_pair = max(w.shape[0] + w.shape[1] for w in weights)
-    return acc_itemsize * bt * (6 * D + widest_pair)
+    state = vmem_tile_bytes((bt, D), acc_dtype)
+    widest_pair = max(vmem_tile_bytes((bt, w.shape[0]), acc_dtype)
+                      + vmem_tile_bytes((bt, w.shape[1]), acc_dtype)
+                      for w in weights)
+    return 6 * state + widest_pair
+
+
+def largest_fitting_chunk(T: int, need: Callable[[int], int],
+                          vmem_budget_bytes: int,
+                          time_chunk: int | None, what: str) -> ChunkPlan:
+    """Shared search of both chunk planners: the largest C in [1, T]
+    with ``need(C) <= vmem_budget_bytes`` (``need`` grows with C), or
+    the explicit ``time_chunk`` after checking that it fits."""
+    mib = lambda n: f"{n / 2 ** 20:.1f} MiB"
+    if time_chunk is not None:
+        C = max(1, min(int(time_chunk), T))
+        if need(C) > vmem_budget_bytes:
+            # fail with a clear message instead of an opaque Mosaic
+            # allocation error at lowering time
+            raise ValueError(
+                f"{what}: time_chunk={C} needs ~{mib(need(C))} VMEM "
+                f"(budget {mib(vmem_budget_bytes)}); shrink time_chunk "
+                f"or batch_tile")
+        return ChunkPlan(C, -(-T // C), need(C))
+    if need(1) > vmem_budget_bytes:
+        raise ValueError(
+            f"{what}: weights + one RK4 step need ~{mib(need(1))} VMEM "
+            f"(budget {mib(vmem_budget_bytes)}); shrink batch_tile or "
+            f"the MLP")
+    lo, hi = 1, max(1, T)
+    while lo < hi:
+        mid = (lo + hi + 1) // 2
+        if need(mid) <= vmem_budget_bytes:
+            lo = mid
+        else:
+            hi = mid - 1
+    return ChunkPlan(lo, -(-T // lo), need(lo))
 
 
 def plan_time_chunk(T: int, bt: int, D: int, du: int, per_tile_drive: bool,
@@ -193,53 +270,35 @@ def plan_time_chunk(T: int, bt: int, D: int, du: int, per_tile_drive: bool,
     """Pick the largest time chunk C whose per-cell working set fits the
     VMEM budget (or honour an explicit ``time_chunk`` override).
 
-    Per-cell bytes, each term sized by the ``precision`` policy's dtypes
-    (``sb``/``ab``/``cb`` = storage/accumulation/carry itemsize):
+    Per-cell bytes, every buffer counted tiled (:func:`vmem_tile_bytes`)
+    and at its policy dtype; pipelined blocks count twice (Pallas
+    double-buffers them):
 
-      sb · (Σ w.size + Σ b.size)     weights + biases (resident)
-      sb · C·bt·D                    the (C, bt, D) output slab
-      sb · (2C+1)·u_width            the drive slab (u_width = Du, or
-                                     bt·Du per-twin)
-      cb · bt·D                      the carry scratch
-      ab · bt · (6·D + max(in+out))  RK4 activation slack (see
-                                     :func:`_rk4_activation_bytes`)
+      2 · (y0 block + weights + biases)   resident operands
+      2 · (C, bt, D) out slab             at the storage dtype
+      2 · drive block                     f32 (:func:`drive_block_shape`)
+      (bt, D) carry scratch               at the carry dtype
+      RK4 activation slack                :func:`_rk4_activation_bytes`
 
-    bf16 storage halves every per-step term, so the planned chunk is
-    ~2x the f32 one at a fixed budget (the weights-must-fit threshold
-    moves by the same factor).
+    bf16 storage halves the out slab, so the planned chunk is ~2x the
+    f32 one at a fixed budget (the weights-must-fit threshold moves by
+    about the same factor).
     """
     store, _, acc, carry = precision_dtypes(resolve_precision(precision))
-    sb = jnp.dtype(store).itemsize
-    ab = jnp.dtype(acc).itemsize
-    cb = jnp.dtype(carry).itemsize
-    u_width = max(du, 1) * (bt if per_tile_drive else 1)
-    wbytes = (sum(sb * w.size for w in weights)
-              + sum(sb * b.size for b in biases))
-    act = _rk4_activation_bytes(bt, D, du, weights, ab)
-    fixed = wbytes + act + cb * bt * D           # + carry
-    per_step = sb * bt * D + 2 * sb * u_width    # out row + two u rows
-    if time_chunk is not None:
-        C = max(1, min(int(time_chunk), T))
-    else:
-        avail = vmem_budget_bytes - fixed - sb * u_width   # the +1 u row
-        C = int(avail // per_step)
-        if C < 1:
-            raise ValueError(
-                f"fused kernel weights + one RK4 step need "
-                f"~{(fixed + per_step + sb * u_width) / 2 ** 20:.1f} MiB VMEM "
-                f"(budget {vmem_budget_bytes / 2 ** 20:.1f}); shrink "
-                f"batch_tile or the MLP")
-        C = min(C, T)
-    need = fixed + sb * C * bt * D + sb * (2 * C + 1) * u_width
-    if need > vmem_budget_bytes:
-        # only reachable with an explicit (oversized) time_chunk — fail
-        # with a clear message instead of an opaque Mosaic allocation
-        # error at lowering time
-        raise ValueError(
-            f"time_chunk={C} needs ~{need / 2 ** 20:.1f} MiB VMEM "
-            f"(budget {vmem_budget_bytes / 2 ** 20:.1f}); shrink "
-            f"time_chunk or batch_tile")
-    return ChunkPlan(C, -(-T // C), need)
+    fixed = (2 * (vmem_tile_bytes((bt, D), jnp.float32)
+                  + sum(vmem_tile_bytes(w.shape, store) for w in weights)
+                  + sum(vmem_tile_bytes(b.shape, store) for b in biases))
+             + vmem_tile_bytes((bt, D), carry)
+             + _rk4_activation_bytes(bt, D, weights, acc))
+
+    def need(C):
+        return fixed + 2 * (
+            vmem_tile_bytes(drive_block_shape(C, bt, du, per_tile_drive),
+                            DRIVE_DTYPE)
+            + vmem_tile_bytes((C, bt, D), store))
+
+    return largest_fitting_chunk(T, need, vmem_budget_bytes, time_chunk,
+                                 "fused kernel")
 
 
 def make_rk4_step(num_layers: int, dt: float, drive_dim: int, bt: int,
@@ -262,7 +321,9 @@ def make_rk4_step(num_layers: int, dt: float, drive_dim: int, bt: int,
         for i in range(num_layers):
             x = jnp.dot(x.astype(compute), ws[i],
                         preferred_element_type=acc)
-            x = x + bs[i][None, :].astype(acc)
+            # widen BEFORE broadcasting: the VJP of a width-1 bias sums
+            # to a scalar, and Mosaic extracts only 32-bit scalars
+            x = x + bs[i].astype(acc)[None, :]
             if i < num_layers - 1:
                 x = jnp.maximum(x, 0.0)
         return x.astype(carry)
@@ -387,6 +448,28 @@ def _chunk_drive(u: jax.Array, C: int, num_chunks: int) -> jax.Array:
     return u[idx]
 
 
+def chunked_drive_operand(u_half: jax.Array, T: int, C: int, NC: int,
+                          bt: int, per_tile_drive: bool, *, reverse: bool):
+    """The drive operand of the fused kernels and its BlockSpec.
+
+    ``u_half`` is (2T+1, Du) shared or (B, 2T+1, Du) per-twin; it is
+    re-slabbed time-major into per-chunk windows (so the kernel's
+    ``u_ref[0, 2t]`` indexing holds) at :data:`DRIVE_DTYPE`.  Grid cell
+    (i, j) reads chunk j — or chunk NC-1-j with ``reverse`` (the
+    backward kernel's walk)."""
+    chunk = (lambda j: NC - 1 - j) if reverse else (lambda j: j)
+    du = u_half.shape[-1]
+    if per_tile_drive:
+        u_tm = jnp.transpose(u_half, (1, 0, 2))          # (2T+1, B, du)
+        index = lambda i, j: (chunk(j), 0, i, 0)
+    else:
+        u_tm = u_half if du > 0 else jnp.zeros((2 * T + 1, 1))
+        index = lambda i, j: (chunk(j), 0, 0)
+    u_in = _chunk_drive(u_tm.astype(DRIVE_DTYPE), C, NC)
+    return u_in, pl.BlockSpec(drive_block_shape(C, bt, du, per_tile_drive),
+                              index)
+
+
 def fused_node_rollout(
     y0: jax.Array,                    # (B, D) float
     u_half: jax.Array,                # (2T+1, Du) shared or (B, 2T+1, Du)
@@ -427,7 +510,7 @@ def fused_node_rollout(
         _require_float(f"biases[{li}]", b, precision)
     weights = [w.astype(store) for w in weights]
     biases = [b.astype(store) for b in biases]
-    u_half = u_half.astype(store)
+    u_half = u_half.astype(DRIVE_DTYPE)
     y0 = y0.astype(jnp.float32)       # the seed block; rounded in-kernel
     B, D = y0.shape
     per_tile_drive = u_half.ndim == 3
@@ -452,17 +535,8 @@ def fused_node_rollout(
                           precision)
 
     grid = (B // bt, NC)                 # time minor: chunks run in order
-    if per_tile_drive:
-        # time-major so the kernel's u_ref[0, 2t] indexing holds
-        u_tm = jnp.transpose(u_half, (1, 0, 2))          # (2T+1, B, du)
-        u_in = _chunk_drive(u_tm, C, NC)                 # (NC, 2C+1, B, du)
-        u_spec = pl.BlockSpec((1, 2 * C + 1, bt, du),
-                              lambda i, j: (j, 0, i, 0))
-    else:
-        u_tm = u_half if du > 0 else jnp.zeros((2 * T + 1, 1), store)
-        u_in = _chunk_drive(u_tm, C, NC)                 # (NC, 2C+1, du')
-        u_spec = pl.BlockSpec((1, 2 * C + 1, max(du, 1)),
-                              lambda i, j: (j, 0, 0))
+    u_in, u_spec = chunked_drive_operand(u_half, T, C, NC, bt,
+                                         per_tile_drive, reverse=False)
     in_specs = [
         pl.BlockSpec((bt, D), lambda i, j: (i, 0)),      # y0
         u_spec,                                          # u_chunks

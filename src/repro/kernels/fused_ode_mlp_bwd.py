@@ -32,8 +32,9 @@ order via the index maps.  Block layout per (i, j) cell (chunk
   a        (bt, D)  scratch  adjoint carried across chunks of one tile
   ys       (C, bt, D) scratch  replayed per-step states of the chunk
 
-VMEM per cell ~= 3x weights (w, dw refs, dw loop carry) + TWO C-slabs
-(replayed states + cotangents) + activation slack for the step VJP —
+VMEM per cell ~= weights (operands, dw refs, dw loop carry) + TWO C-slabs
+(replayed states + cotangents) + activation slack for the step VJP,
+counted tiled like the forward's (``vmem_tile_bytes``) —
 roughly twice the forward's footprint, so ``plan_bwd_time_chunk`` packs
 a (usually smaller) chunk against the same budget.  The boundary states
 are FREE residuals: the forward's output trajectory already contains
@@ -45,8 +46,9 @@ Gradients are taken w.r.t. ``y0``, ``weights`` and ``biases``; the drive
 signal, not a parameter.
 
 Mixed precision mirrors the forward's ``precision`` policy: the
-boundary states, drive slabs, cotangent slabs and weight operands
-stream at the storage dtype (bf16 under the bf16 policies), the replay
+boundary states, cotangent slabs and weight operands stream at the
+storage dtype (bf16 under the bf16 policies; the drive stays f32, as
+in the forward), the replay
 and the adjoint run at the carry dtype, and the dW/db gradient
 accumulators — both the in-loop carry and the constant-index-map VMEM
 output blocks — ALWAYS stay float32, so reduced storage never costs
@@ -63,11 +65,15 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET, ChunkPlan,
-                                         _chunk_drive, _default_interpret,
+from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET, DRIVE_DTYPE,
+                                         ChunkPlan, _default_interpret,
                                          _rk4_activation_bytes,
-                                         fused_node_rollout, make_rk4_step,
-                                         precision_dtypes, resolve_precision)
+                                         chunked_drive_operand,
+                                         drive_block_shape,
+                                         fused_node_rollout,
+                                         largest_fitting_chunk,
+                                         make_rk4_step, precision_dtypes,
+                                         resolve_precision, vmem_tile_bytes)
 
 
 def plan_bwd_time_chunk(T: int, bt: int, D: int, du: int,
@@ -77,45 +83,34 @@ def plan_bwd_time_chunk(T: int, bt: int, D: int, du: int,
                         vmem_budget_bytes: int,
                         time_chunk: int | None = None,
                         precision: str = "f32") -> ChunkPlan:
-    """Backward-pass chunk planner: same contract as ``plan_time_chunk``
-    but for the heavier reverse working set — weights appear three times
-    (operands at the storage dtype, plus the gradient-accumulator refs
-    and the fori_loop gradient carry, both ALWAYS f32), every chunk
-    keeps TWO (C, bt, D) slabs resident (the replayed states at the
-    carry dtype and the cotangents at the storage dtype), and the step
-    VJP's activation slack is twice the forward's (primal residuals +
+    """Backward-pass chunk planner: same contract and tiled accounting as
+    ``plan_time_chunk``, for the heavier reverse working set.  Pipelined
+    blocks (count twice): the boundary row, the drive, the (C, bt, D)
+    cotangent slab and the weights at the storage dtype; the dy0 block
+    and the dW/db accumulator blocks, ALWAYS f32.  Once each: the adjoint
+    and the (C, bt, D) replayed-state scratch at the carry dtype, the
+    f32 gradient carry of the reverse loop plus one step's increment,
+    and twice the forward's activation slack (primal residuals and
     cotangents live together)."""
     store, _, acc, carry = precision_dtypes(resolve_precision(precision))
-    sb = jnp.dtype(store).itemsize
-    ab = jnp.dtype(acc).itemsize
-    cb = jnp.dtype(carry).itemsize
-    u_width = max(du, 1) * (bt if per_tile_drive else 1)
-    wsize = sum(w.size for w in weights) + sum(b.size for b in biases)
-    # operands at storage width; dw refs + the dw loop carry stay f32
-    wbytes = sb * wsize + 2 * 4 * wsize
-    act = 2 * _rk4_activation_bytes(bt, D, du, weights, ab)
-    # + boundary row (store), adjoint carry (f32), dy0 block (f32)
-    fixed = wbytes + act + sb * bt * D + 2 * 4 * bt * D
-    per_step = (cb + sb) * bt * D + 2 * sb * u_width  # ys + g + two u rows
-    if time_chunk is not None:
-        C = max(1, min(int(time_chunk), T))
-    else:
-        avail = vmem_budget_bytes - fixed - sb * u_width
-        C = int(avail // per_step)
-        if C < 1:
-            raise ValueError(
-                f"fused backward: weights + one reverse RK4 step need "
-                f"~{(fixed + per_step + sb * u_width) / 2 ** 20:.1f} MiB VMEM "
-                f"(budget {vmem_budget_bytes / 2 ** 20:.1f}); shrink "
-                f"batch_tile or the MLP")
-        C = min(C, T)
-    need = fixed + (cb + sb) * C * bt * D + sb * (2 * C + 1) * u_width
-    if need > vmem_budget_bytes:
-        raise ValueError(
-            f"backward time_chunk={C} needs ~{need / 2 ** 20:.1f} MiB VMEM "
-            f"(budget {vmem_budget_bytes / 2 ** 20:.1f}); shrink "
-            f"time_chunk or batch_tile")
-    return ChunkPlan(C, -(-T // C), need)
+    f32 = jnp.float32
+    params = list(weights) + list(biases)
+    grads = sum(vmem_tile_bytes(p.shape, f32) for p in params)
+    fixed = (2 * (vmem_tile_bytes((1, bt, D), store)
+                  + sum(vmem_tile_bytes(p.shape, store) for p in params)
+                  + vmem_tile_bytes((bt, D), f32) + grads)
+             + vmem_tile_bytes((bt, D), carry) + 2 * grads
+             + 2 * _rk4_activation_bytes(bt, D, weights, acc))
+
+    def need(C):
+        return (fixed + vmem_tile_bytes((C, bt, D), carry)
+                + 2 * (vmem_tile_bytes(
+                    drive_block_shape(C, bt, du, per_tile_drive),
+                    DRIVE_DTYPE)
+                       + vmem_tile_bytes((C, bt, D), store)))
+
+    return largest_fitting_chunk(T, need, vmem_budget_bytes, time_chunk,
+                                 "fused backward")
 
 
 def _make_bwd_kernel(num_layers: int, C: int, dt: float,
@@ -226,14 +221,15 @@ def fused_node_rollout_bwd(
     ``y_bounds[jj]`` must be the state at the START of chunk jj (forward
     trajectory row ``jj*C``); ``g_steps`` are the cotangents of the
     forward's per-step outputs (trajectory rows 1..T — the y0 row's
-    cotangent is added by the caller).  ``y_bounds``, ``u_half``,
+    cotangent is added by the caller).  ``y_bounds``,
     ``weights``/``biases`` and ``g_steps`` are expected at the policy's
-    storage dtype (the caller casts).
+    storage dtype (the caller casts); the drive is cast to
+    ``DRIVE_DTYPE`` here.
     """
     if interpret is None:
         interpret = _default_interpret()
     precision = resolve_precision(precision)
-    store, _, _, carry_dt = precision_dtypes(precision)
+    carry_dt = precision_dtypes(precision)[3]
     NC, B, D = y_bounds.shape
     C = int(time_chunk)
     per_tile_drive = u_half.ndim == 3
@@ -256,16 +252,8 @@ def fused_node_rollout_bwd(
                               precision)
 
     grid = (B // bt, NC)
-    if per_tile_drive:
-        u_tm = jnp.transpose(u_half, (1, 0, 2))          # (2T+1, B, du)
-        u_in = _chunk_drive(u_tm, C, NC)                 # (NC, 2C+1, B, du)
-        u_spec = pl.BlockSpec((1, 2 * C + 1, bt, du),
-                              lambda i, j: (NC - 1 - j, 0, i, 0))
-    else:
-        u_tm = u_half if du > 0 else jnp.zeros((2 * T + 1, 1), store)
-        u_in = _chunk_drive(u_tm, C, NC)                 # (NC, 2C+1, du')
-        u_spec = pl.BlockSpec((1, 2 * C + 1, max(du, 1)),
-                              lambda i, j: (NC - 1 - j, 0, 0))
+    u_in, u_spec = chunked_drive_operand(u_half, T, C, NC, bt,
+                                         per_tile_drive, reverse=True)
     in_specs = [
         pl.BlockSpec((1, bt, D), lambda i, j: (NC - 1 - j, i, 0)),  # bounds
         u_spec,
@@ -387,7 +375,7 @@ def _rollout_bwd(dt, batch_tile, time_chunk, interpret, vmem_budget_bytes,
     # the kernel consumes the storage-dtype operands the forward ran on
     weights = [w.astype(store) for w in weights]
     biases = [b.astype(store) for b in biases]
-    u_half = u_half.astype(store)
+    u_half = u_half.astype(DRIVE_DTYPE)
     B, D = traj.shape[1], traj.shape[2]
     per_tile_drive = u_half.ndim == 3
     if per_tile_drive and u_half.shape[-1] == 0:

@@ -81,7 +81,7 @@ def fused_node_rollout(params: Sequence[dict], y0: jax.Array,
       precision: mixed-precision policy — ``"f32"`` | ``"bf16"`` |
         ``"bf16_f32acc"``, or ``None`` for the platform default
         (bf16_f32acc on TPU, f32 elsewhere).  The bf16 policies store
-        weights, drive and trajectory slabs at half width while matmuls
+        weights and trajectory slabs at half width while matmuls
         accumulate at f32 (``bf16_f32acc``) and gradient accumulators
         always stay f32; the error model is documented in
         ``docs/kernels.md``.  Non-floating inputs raise a ``ValueError``
@@ -391,7 +391,7 @@ def _sdtw_cost_slab(x, y, chunk, precision):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def soft_dtw(x: jax.Array, y: jax.Array, gamma: float = 1.0,
-             interpret: bool = True,
+             interpret: bool | None = None,
              precision: str | None = None) -> jax.Array:
     """Batched soft-DTW((B,n,d),(B,m,d)) -> (B,) via the wavefront kernel.
 
@@ -399,6 +399,7 @@ def soft_dtw(x: jax.Array, y: jax.Array, gamma: float = 1.0,
     = platform default).  Under the bf16 policies the cost matrix
     streams through the kernel at bfloat16 while the R/E diagonal
     carries and the answer stay float32 (see ``docs/kernels.md``).
+    ``interpret=None`` auto-detects (compiled on TPU).
     """
     n, m = x.shape[1], y.shape[1]
     chunk = _sdtw_chunk(n, m)
@@ -440,7 +441,7 @@ soft_dtw.defvjp(_sdtw_fwd, _sdtw_bwd)
 
 
 def dtw_distance(x: jax.Array, y: jax.Array, *,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """Batched hard-DTW metric via the same wavefront kernel."""
     D = jax.vmap(_pairwise_dist)(x, y)
     n, m = D.shape[1], D.shape[2]

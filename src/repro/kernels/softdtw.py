@@ -29,13 +29,15 @@ Grid: (batch, num_k_chunks); the backward's chunk grid dimension is
 index-mapped in reverse.
 
 Mixed precision: the cost matrix ``dd`` — the only O(n·m) input — may
-arrive in bfloat16 (the ``"bf16"``/``"bf16_f32acc"`` policies halve its
-VMEM/HBM traffic); each wavefront row is upcast once on read and the
-R/E/D diagonal carries, the accumulated answer and the emitted R and E
-matrices ALWAYS stay float32 — the sequential DP recurrences are where
-reduced precision would compound.  The BIG padding sentinel is detected
-with a half-BIG threshold because bf16 rounds ``1e10`` slightly DOWN
-(an exact ``>= BIG`` compare would mistake padded cells for real ones).
+arrive rounded to bfloat16 (the ``"bf16"``/``"bf16_f32acc"`` policies);
+the wrappers widen it to float32 before the kernel (a wavefront reads
+one row at a dynamic index, which Mosaic cannot do on a bf16 slab), and
+the R/E/D diagonal carries, the accumulated answer and the emitted R
+and E matrices ALWAYS stay float32 — the sequential DP recurrences are
+where reduced precision would compound.  The BIG padding sentinel is
+detected with a half-BIG threshold because bf16 rounds ``1e10``
+slightly DOWN (an exact ``>= BIG`` compare would mistake padded cells
+for real ones).
 """
 from __future__ import annotations
 
@@ -48,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.losses import BIG
+from repro.kernels.fused_ode_mlp import _default_interpret
 
 # padding-sentinel threshold: robust to BIG's bf16 rounding (see module
 # docstring); real costs are pairwise distances, orders of magnitude
@@ -82,7 +85,7 @@ def _kernel(dd_ref, *refs, n: int, m: int, chunk: int, nkc: int,
 
     def body(r, _):
         k = kc * chunk + r
-        d_k = dd_ref[0, r].astype(jnp.float32)   # bf16 slab upcast once
+        d_k = dd_ref[0, r]
         rp = rp_ref[...]
         rp2 = rp2_ref[...]
         up = rp
@@ -97,14 +100,16 @@ def _kernel(dd_ref, *refs, n: int, m: int, chunk: int, nkc: int,
         rp_ref[...] = r_k
         if with_r:
             r_dd_ref[0, r] = r_k
-        ans_ref[0] = jnp.where(k == n + m - 2, r_k[n - 1], ans_ref[0])
+        # the last anti-diagonal holds the answer at row n-1; keep the
+        # whole row (Mosaic cannot store a scalar to VMEM)
+        ans_ref[...] = jnp.where(k == n + m - 2, r_k, ans_ref[...])
         return 0
 
     lax.fori_loop(0, chunk, body, 0)
 
     @pl.when(kc == nkc - 1)
     def _finish():
-        out_ref[0] = ans_ref[0]
+        out_ref[...] = ans_ref[...].reshape(out_ref.shape)
 
 
 def softdtw_pallas(
@@ -114,25 +119,34 @@ def softdtw_pallas(
     gamma: float = 1.0,
     hard: bool = False,
     chunk: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
     return_r: bool = False,
 ):
     """Batched accumulated (soft-)DTW from diagonal-layout costs -> (B,).
 
     ``dd`` may be float32 or bfloat16 (the reduced-precision policies
-    stream the cost slab at half width); the DP carries and the output
-    are always float32.  ``return_r=True`` also returns the
+    round the costs to bf16); it is widened to float32 before the
+    kernel, because Mosaic cannot read one bf16 row at a dynamic index
+    (two rows share a sublane).  The DP carries and the output are
+    always float32.  ``return_r=True`` also returns the
     accumulated-cost matrix R (float32) in the same (B, KD_pad, n)
-    diagonal layout — the backward pass's residual.
+    diagonal layout — the backward pass's residual.  ``interpret=None``
+    auto-detects like the fused kernels (compiled on TPU).
     """
+    if interpret is None:
+        interpret = _default_interpret()
     B, kd_pad, n_ = dd.shape
     assert n_ == n and kd_pad % chunk == 0
+    dd = dd.astype(jnp.float32)
     nkc = kd_pad // chunk
     kernel = functools.partial(_kernel, n=n, m=m, chunk=chunk, nkc=nkc,
                                gamma=float(gamma), hard=hard,
                                with_r=return_r)
-    out_shape = [jax.ShapeDtypeStruct((B,), jnp.float32)]
-    out_specs = [pl.BlockSpec((1,), lambda b, kc: (b,))]
+    # one (1, n) block per series holding its last anti-diagonal (the
+    # answer is entry n-1): Mosaic refuses a (1,) block of a rank-1 (B,)
+    # output, while a block equal to the trailing dims is always legal
+    out_shape = [jax.ShapeDtypeStruct((B, 1, n), jnp.float32)]
+    out_specs = [pl.BlockSpec((1, 1, n), lambda b, kc: (b, 0, 0))]
     if return_r:
         out_shape.append(jax.ShapeDtypeStruct((B, kd_pad, n), jnp.float32))
         out_specs.append(pl.BlockSpec((1, chunk, n), lambda b, kc: (b, kc, 0)))
@@ -144,10 +158,12 @@ def softdtw_pallas(
         out_shape=out_shape if return_r else out_shape[0],
         scratch_shapes=[pltpu.VMEM((n,), jnp.float32),
                         pltpu.VMEM((n,), jnp.float32),
-                        pltpu.VMEM((1,), jnp.float32)],
+                        pltpu.VMEM((n,), jnp.float32)],
         interpret=interpret,
     )(dd)
-    return outs
+    if return_r:
+        return outs[0][:, 0, n - 1], outs[1]
+    return outs[:, 0, n - 1]
 
 
 def _bwd_kernel(dd_ref, rd_ref, e_dd_ref, e1_ref, e2_ref, r1_ref, r2_ref,
@@ -164,9 +180,10 @@ def _bwd_kernel(dd_ref, rd_ref, e_dd_ref, e1_ref, e2_ref, r1_ref, r2_ref,
     reverse)."""
     kc_rev = pl.program_id(1)
     inv_g = 1.0 / gamma
-    # one-hot of row n-1 (1-D iota is not lowerable on TPU)
-    seed_row = jnp.concatenate([jnp.zeros((n - 1,), jnp.float32),
-                                jnp.ones((1,), jnp.float32)])
+    # one-hot of cell row n-1, which is row 0 of the reversed layout
+    # (1-D iota is not lowerable on TPU)
+    seed_row = jnp.concatenate([jnp.ones((1,), jnp.float32),
+                                jnp.zeros((n - 1,), jnp.float32)])
 
     @pl.when(kc_rev == 0)
     def _init():
@@ -178,13 +195,14 @@ def _bwd_kernel(dd_ref, rd_ref, e_dd_ref, e1_ref, e2_ref, r1_ref, r2_ref,
         d2_ref[...] = jnp.full_like(d2_ref, BIG)
 
     def shift(x, pad):
-        """layout row index i -> i+1 (children live one row down)."""
-        return jnp.concatenate([x[1:], jnp.full((1,), pad, x.dtype)])
+        """cell row i -> i+1 (children live one row down), i.e. one
+        lane up in the reversed layout."""
+        return jnp.concatenate([jnp.full((1,), pad, x.dtype), x[:-1]])
 
     def body(s, _):
         r = chunk - 1 - s
         k = (nkc - 1 - kc_rev) * chunk + r
-        d_k = dd_ref[0, r].astype(jnp.float32)   # bf16 slab upcast once
+        d_k = dd_ref[0, r]
         r_k = rd_ref[0, r]
         e1, e2 = e1_ref[...], e2_ref[...]
         r1, r2 = r1_ref[...], r2_ref[...]
@@ -219,15 +237,25 @@ def softdtw_bwd_pallas(
     *,
     gamma: float = 1.0,
     chunk: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """E-matrix (dSDTW/dD) in diagonal layout, (B, KD_pad, n) float32.
 
     ``dd`` may be bfloat16 (matching the forward's reduced-precision
-    cost slab); ``rd`` is the forward's float32 R and the E/R/D diagonal
-    carries stay float32."""
+    cost slab; widened here as in the forward); ``rd`` is the forward's
+    float32 R and the E/R/D diagonal carries stay float32.
+    ``interpret=None`` auto-detects.
+
+    The kernel runs on the row-reversed layout (lane p holds cell row
+    n-1-p), so every read of a child one row down is a shift by one lane
+    toward the end — the direction Mosaic lowers; the result is flipped
+    back here."""
+    if interpret is None:
+        interpret = _default_interpret()
     B, kd_pad, n_ = dd.shape
     assert n_ == n and kd_pad % chunk == 0 and rd.shape == dd.shape
+    dd = dd.astype(jnp.float32)[..., ::-1]
+    rd = rd[..., ::-1]
     nkc = kd_pad // chunk
     kernel = functools.partial(_bwd_kernel, n=n, m=m, chunk=chunk, nkc=nkc,
                                gamma=float(gamma))
@@ -241,4 +269,4 @@ def softdtw_bwd_pallas(
         out_shape=jax.ShapeDtypeStruct((B, kd_pad, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n,), jnp.float32)] * 6,
         interpret=interpret,
-    )(dd, rd)
+    )(dd, rd)[..., ::-1]

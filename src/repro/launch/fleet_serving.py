@@ -51,7 +51,6 @@ from typing import Any, Callable, Iterable, Iterator, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.backends import (AnalogueBackend, DigitalBackend,
@@ -187,14 +186,14 @@ def shard_rollout_batch(backend, state, y0s: jax.Array, ts: jax.Array, *,
             drive_params=dp_loc, **solver_kw)
 
     if dp_p is None:
-        sharded = shard_map(lambda y: per_device(y, None), mesh=mesh,
-                            in_specs=P(TWIN_AXIS),
-                            out_specs=P(TWIN_AXIS), check_rep=False)
+        sharded = jax.shard_map(lambda y: per_device(y, None), mesh=mesh,
+                                in_specs=P(TWIN_AXIS),
+                                out_specs=P(TWIN_AXIS), check_vma=False)
         out = sharded(y0s_p)
     else:
-        sharded = shard_map(per_device, mesh=mesh,
-                            in_specs=(P(TWIN_AXIS), P(TWIN_AXIS)),
-                            out_specs=P(TWIN_AXIS), check_rep=False)
+        sharded = jax.shard_map(per_device, mesh=mesh,
+                                in_specs=(P(TWIN_AXIS), P(TWIN_AXIS)),
+                                out_specs=P(TWIN_AXIS), check_vma=False)
         out = sharded(y0s_p, dp_p)
     return out[:n]
 
@@ -1453,7 +1452,9 @@ def main(argv=None):
                          "weights saved to a temp dir — substrate smoke)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.train import recipes
+    enable_compile_cache()
     backend = args.backend
     if args.precision is not None:
         if backend != "fused_pallas":

@@ -38,7 +38,11 @@ def make_twin_mesh(n_devices: Optional[int] = None) -> jax.sharding.Mesh:
     if not 1 <= n <= len(devs):
         raise ValueError(
             f"make_twin_mesh: asked for {n} devices, have {len(devs)}")
-    return jax.make_mesh((n,), (TWIN_AXIS,), devices=devs[:n])
+    # Auto axes: the serving shard_map closes over the replicated
+    # weights, which jax refuses for inputs sharded on Explicit axes
+    # (make_mesh's default).
+    return jax.make_mesh((n,), (TWIN_AXIS,), devices=devs[:n],
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def twin_shard_count(mesh) -> int:
