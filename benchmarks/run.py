@@ -246,16 +246,19 @@ def bench_fig4j_noise(l96_state=None):
 
 def _fused_hbm_bytes(T, B, D, du, wsize, precision, bwd=False):
     """Modelled HBM bytes of one fused rollout (VJP adds the reverse
-    sweep): y0 in (always f32) + drive slab + weights in + trajectory
-    slab out, every slab at the policy's storage width; the backward
-    additionally streams the cotangent slab in and flushes the f32
-    dW/db accumulators + dy0 (boundary rows are re-read from the primal
-    trajectory, already counted).  This is the quantity the bf16
-    policies halve — the achieved-bandwidth column divides it by the
-    measured wall time."""
+    sweep): y0 and the drive slab in (always f32, ``DRIVE_DTYPE``) +
+    weights in + trajectory slab out, those two at the policy's storage
+    width; the backward additionally streams the cotangent slab in and
+    flushes the f32 dW/db accumulators + dy0 (boundary rows are re-read
+    from the primal trajectory, already counted).  The weights and
+    trajectory are what the bf16 policies halve — the achieved-bandwidth
+    column divides the total by the measured wall time."""
+    import jax.numpy as jnp
+    from repro.kernels.fused_ode_mlp import DRIVE_DTYPE
     sb = 2 if precision != "f32" else 4
     uh = (2 * T + 1) * max(du, 1)
-    n = B * D * 4 + uh * sb + wsize * sb + T * B * D * sb
+    n = (B * D * 4 + uh * jnp.dtype(DRIVE_DTYPE).itemsize + wsize * sb
+         + T * B * D * sb)
     if bwd:
         n += T * B * D * sb + wsize * 4 + B * D * 4
     return n

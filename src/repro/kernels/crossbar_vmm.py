@@ -44,8 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fused_ode_mlp import _default_interpret
 from repro.kernels.noise import counter_normal, stuck_cell_masks
+from repro.kernels.platform import default_interpret
 
 
 def pad_accumulator_neutral(x: jax.Array, mult: int, axis: int) -> jax.Array:
@@ -191,7 +191,7 @@ def crossbar_matmul(
     static read-disturb relaxation factor.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     M, K = x.shape
     K2, N = gp.shape
     assert K == K2 and gm.shape == gp.shape

@@ -50,11 +50,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET,
-                                         _default_interpret,
                                          chunked_drive_operand,
                                          plan_time_chunk)
 from repro.kernels.crossbar_vmm import conductance_f32
 from repro.kernels.noise import counter_normal, stuck_cell_masks
+from repro.kernels.platform import default_interpret
 
 #: Static fault parameters the kernel understands (subset optional);
 #: produced by ``FaultModel.kernel_args()`` in :mod:`repro.core.faults`.
@@ -251,7 +251,7 @@ def fused_analogue_rollout(
     distinct offset); noise-free, drift-free solves ignore it.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     if read_noise > 0.0 and g_step is not None and g_min <= 0.0:
         raise ValueError(
             "fused_analogue_rollout: noisy quantised reads need the "

@@ -75,7 +75,6 @@ bitwise parity.
 from __future__ import annotations
 
 import math
-import os
 from typing import Callable, NamedTuple, Sequence
 
 import jax
@@ -83,6 +82,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.platform import default_interpret
 
 
 #: Per-cell VMEM the chunk planners fill.  Mosaic's default scoped-VMEM
@@ -98,26 +99,6 @@ DRIVE_DTYPE = jnp.float32
 
 #: Supported precision policies (see the module docstring's error model).
 PRECISIONS = ("f32", "bf16", "bf16_f32acc")
-
-
-def _default_interpret() -> bool:
-    """Compiled lowering on TPU, interpreter everywhere else — so CPU/GPU
-    hosts validate the kernel while TPU runs never silently benchmark the
-    interpreter.  ``REPRO_FORCE_INTERPRET=1`` (or ``0``) pins the mode
-    regardless of the detected accelerator, so CI and local debugging can
-    force the interpreter (or a compiled lowering) without monkeypatching;
-    an empty/unset variable keeps the auto-detect."""
-    env = os.environ.get("REPRO_FORCE_INTERPRET", "").strip().lower()
-    if env:
-        truthy = {"1", "true", "yes", "on"}
-        falsy = {"0", "false", "no", "off"}
-        if env not in truthy | falsy:
-            raise ValueError(
-                f"REPRO_FORCE_INTERPRET={env!r} not understood; use one "
-                f"of {sorted(truthy)} / {sorted(falsy)} (or unset it for "
-                f"accelerator auto-detect)")
-        return env in truthy
-    return jax.default_backend() != "tpu"
 
 
 def default_precision() -> str:
@@ -143,7 +124,8 @@ def precision_dtypes(precision: str):
               VMEM-resident operand blocks; the drive stays at
               :data:`DRIVE_DTYPE`);
     compute — matmul operand dtype fed to the MXU;
-    acc     — ``preferred_element_type`` of every in-kernel ``jnp.dot``;
+    acc     — dtype of every in-kernel ``jnp.dot`` result (the MXU sums
+              at f32; pure ``"bf16"`` rounds the sum to bf16);
     carry   — the RK4 integration state in VMEM scratch.
     """
     if precision == "f32":
@@ -311,16 +293,21 @@ def make_rk4_step(num_layers: int, dt: float, drive_dim: int, bt: int,
     exactly one definition of the step.
 
     Under a bf16 ``precision`` policy the matmul operands are cast to
-    the compute dtype (MXU-native bf16) and every ``jnp.dot`` names the
-    policy's accumulation dtype via ``preferred_element_type``; the
+    the compute dtype (MXU-native bf16) and every ``jnp.dot`` sums at
+    f32 and rounds to the policy's accumulation dtype; the
     surrounding RK4 arithmetic runs at the carry dtype (f32 for
     ``"bf16_f32acc"``), so only the MXU operands are reduced."""
     _, compute, acc, carry = precision_dtypes(resolve_precision(precision))
+    # Mosaic's default f32 matmul is one bf16 MXU pass; the f32 policy
+    # asks for full f32 products (interpret mode computes them anyway)
+    dot_precision = lax.Precision.HIGHEST if compute == jnp.float32 else None
 
     def mlp(x, ws, bs):
         for i in range(num_layers):
-            x = jnp.dot(x.astype(compute), ws[i],
-                        preferred_element_type=acc)
+            # the MXU accumulates at f32 (Mosaic refuses a bf16
+            # accumulator); pure "bf16" rounds the sum once afterwards
+            x = jnp.dot(x.astype(compute), ws[i], precision=dot_precision,
+                        preferred_element_type=jnp.float32).astype(acc)
             # widen BEFORE broadcasting: the VJP of a width-1 bias sums
             # to a scalar, and Mosaic extracts only 32-bit scalars
             x = x + bs[i].astype(acc)[None, :]
@@ -500,7 +487,7 @@ def fused_node_rollout(
     lowering error.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     precision = resolve_precision(precision)
     store, _, _, carry = precision_dtypes(precision)
     _require_float("y0", y0, precision)

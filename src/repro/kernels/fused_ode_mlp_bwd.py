@@ -66,7 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET, DRIVE_DTYPE,
-                                         ChunkPlan, _default_interpret,
+                                         ChunkPlan,
                                          _rk4_activation_bytes,
                                          chunked_drive_operand,
                                          drive_block_shape,
@@ -74,6 +74,7 @@ from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET, DRIVE_DTYPE,
                                          largest_fitting_chunk,
                                          make_rk4_step, precision_dtypes,
                                          resolve_precision, vmem_tile_bytes)
+from repro.kernels.platform import default_interpret
 
 
 def plan_bwd_time_chunk(T: int, bt: int, D: int, du: int,
@@ -227,7 +228,7 @@ def fused_node_rollout_bwd(
     ``DRIVE_DTYPE`` here.
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     precision = resolve_precision(precision)
     carry_dt = precision_dtypes(precision)[3]
     NC, B, D = y_bounds.shape

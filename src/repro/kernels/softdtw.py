@@ -50,7 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.losses import BIG
-from repro.kernels.fused_ode_mlp import _default_interpret
+from repro.kernels.platform import default_interpret
 
 # padding-sentinel threshold: robust to BIG's bf16 rounding (see module
 # docstring); real costs are pairwise distances, orders of magnitude
@@ -134,7 +134,7 @@ def softdtw_pallas(
     auto-detects like the fused kernels (compiled on TPU).
     """
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     B, kd_pad, n_ = dd.shape
     assert n_ == n and kd_pad % chunk == 0
     dd = dd.astype(jnp.float32)
@@ -251,7 +251,7 @@ def softdtw_bwd_pallas(
     toward the end — the direction Mosaic lowers; the result is flipped
     back here."""
     if interpret is None:
-        interpret = _default_interpret()
+        interpret = default_interpret()
     B, kd_pad, n_ = dd.shape
     assert n_ == n and kd_pad % chunk == 0 and rd.shape == dd.shape
     dd = dd.astype(jnp.float32)[..., ::-1]

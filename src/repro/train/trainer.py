@@ -276,9 +276,8 @@ def _segment_objective(loss: str, gamma: float, preds, ys_seg,
     preds = preds.astype(jnp.float32)      # bf16 rollouts meet f32 targets
     if kernelised and loss != "l1":
         from repro.kernels import ops
-        from repro.kernels.fused_ode_mlp import _default_interpret
-        itp = _default_interpret() if interpret is None else interpret
-        sdtw = jnp.mean(ops.soft_dtw(preds, ys_seg, gamma, itp, precision))
+        sdtw = jnp.mean(ops.soft_dtw(preds, ys_seg, gamma, interpret,
+                                     precision))
     elif loss != "l1":
         per_seg = jax.vmap(lambda p, t: soft_dtw(p, t, gamma))(preds, ys_seg)
         sdtw = jnp.mean(per_seg)

@@ -112,12 +112,12 @@ def test_fused_rejects_non_rk4(hp_setup):
 
 
 def test_interpret_autodetect_off_tpu():
-    from repro.kernels.fused_ode_mlp import _default_interpret
+    from repro.kernels.platform import default_interpret
     if jax.default_backend() == "tpu":
-        assert _default_interpret() is False
+        assert default_interpret() is False
     else:
         # CPU/GPU hosts must fall back to the Pallas interpreter
-        assert _default_interpret() is True
+        assert default_interpret() is True
 
 
 # ---------------------------------------------------------------------------

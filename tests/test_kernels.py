@@ -182,26 +182,24 @@ def test_fused_node_rejects_non_float_inputs():
 
 
 def test_force_interpret_env_override(monkeypatch):
-    """REPRO_FORCE_INTERPRET pins the lowering mode for BOTH kernel
-    modules without monkeypatching jax (ISSUE satellite)."""
-    from repro.kernels import fused_ode_mlp, fused_ode_mlp_bwd
+    """REPRO_FORCE_INTERPRET pins the lowering mode of every kernel
+    module (they all resolve ``interpret=None`` through
+    ``platform.default_interpret``) without monkeypatching jax."""
+    from repro.kernels.platform import default_interpret
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
-    assert fused_ode_mlp._default_interpret() is True
-    assert fused_ode_mlp_bwd._default_interpret() is True
+    assert default_interpret() is True
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "0")
-    assert fused_ode_mlp._default_interpret() is False
-    assert fused_ode_mlp_bwd._default_interpret() is False
+    assert default_interpret() is False
     # common boolean-env spellings work; garbage names the variable
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "true")
-    assert fused_ode_mlp._default_interpret() is True
+    assert default_interpret() is True
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "off")
-    assert fused_ode_mlp._default_interpret() is False
+    assert default_interpret() is False
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "maybe")
     with pytest.raises(ValueError, match="REPRO_FORCE_INTERPRET"):
-        fused_ode_mlp._default_interpret()
+        default_interpret()
     monkeypatch.setenv("REPRO_FORCE_INTERPRET", "")
-    assert (fused_ode_mlp._default_interpret()
-            is (jax.default_backend() != "tpu"))
+    assert default_interpret() is (jax.default_backend() != "tpu")
 
 
 @pytest.mark.parametrize("precision", ["bf16_f32acc", "bf16"])
