@@ -135,6 +135,29 @@ def test_fused_node_precision_parity(precision):
     assert rel <= PRECISION_REL_TOL[precision]
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("precision", ["f32", "bf16_f32acc"])
+def test_fused_node_matmul_precision(precision, grad):
+    """Under ``f32`` every in-kernel matmul asks for full-precision
+    products: Mosaic's default f32 matmul is one bf16 MXU pass, which the
+    interpreter does not show (2.3e-3 off the f32 reference on a v5e at
+    HP 256 x 500).  The bf16 policies keep the default."""
+    import re
+    params = mlp_init(KEY, (2, 14, 14, 1))
+    y0 = jnp.zeros((8, 1))
+    uh = jnp.zeros((2 * 10 + 1, 1))
+
+    def run(p):
+        out = ops.fused_node_rollout(p, y0, uh, 1e-3, precision=precision)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.grad(run) if grad else run)(params))
+    dots = re.findall(r"dot_general\[(.*?)\n\s*\]", text, re.DOTALL)
+    assert dots
+    highest = ["Precision.HIGHEST" in d for d in dots]
+    assert all(highest) if precision == "f32" else not any(highest)
+
+
 def test_plan_time_chunk_bf16_doubles_chunk():
     """The ISSUE acceptance: dtype-aware planning must give bf16 >= 1.8x
     the f32 time chunk at the default VMEM budget (and the plan must
