@@ -1,0 +1,2 @@
+"""The chip benchmark: one command runs one cell of ``BENCHMARK.json``
+(see ``run.py``)."""
