@@ -241,6 +241,7 @@ def crossbar_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="crossbar_vmm",
         interpret=interpret,
     )(xp, gpp, gmp)
     return out[:M, :N]

@@ -328,6 +328,7 @@ def fused_analogue_rollout(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((NC * C, B, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bt, D), jnp.float32)],
+        name="fused_analogue",
         interpret=interpret,
     )(y0, u_in, *gps, *gms, scales)
     return jnp.concatenate([y0[None], steps[:T]], axis=0)

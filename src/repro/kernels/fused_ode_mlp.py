@@ -541,6 +541,7 @@ def fused_node_rollout(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((NC * C, B, D), store),
         scratch_shapes=[pltpu.VMEM((bt, D), carry)],
+        name="fused_fwd",
         interpret=interpret,
     )(y0, u_in, *weights, *biases)
     # Row k of ``steps`` is y after step k; prepend y0, drop the padded

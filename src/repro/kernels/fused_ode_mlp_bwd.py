@@ -284,6 +284,7 @@ def fused_node_rollout_bwd(
         out_shape=out_shapes,
         scratch_shapes=[pltpu.VMEM((bt, D), carry_dt),     # adjoint
                         pltpu.VMEM((C, bt, D), carry_dt)], # replayed ys
+        name="fused_bwd",
         interpret=interpret,
     )(y_bounds, u_in, g_steps, *weights, *biases)
     dy0, dws, dbs = outs[0], list(outs[1:1 + L]), list(outs[1 + L:])

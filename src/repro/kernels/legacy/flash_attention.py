@@ -102,6 +102,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq, dv), jnp.float32)],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)
 

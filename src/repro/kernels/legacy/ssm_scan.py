@@ -82,6 +82,7 @@ def ssm_scan(dt: jax.Array, b: jax.Array, c: jax.Array, x: jax.Array,
             jax.ShapeDtypeStruct((bsz, di, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d_tile, n), jnp.float32)],
+        name="ssm_scan",
         interpret=interpret,
     )(dt, b, c, x, a)
     return y, h_final

@@ -159,6 +159,7 @@ def softdtw_pallas(
         scratch_shapes=[pltpu.VMEM((n,), jnp.float32),
                         pltpu.VMEM((n,), jnp.float32),
                         pltpu.VMEM((n,), jnp.float32)],
+        name="softdtw_fwd",
         interpret=interpret,
     )(dd)
     if return_r:
@@ -268,5 +269,6 @@ def softdtw_bwd_pallas(
         out_specs=pl.BlockSpec((1, chunk, n), rev),
         out_shape=jax.ShapeDtypeStruct((B, kd_pad, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n,), jnp.float32)] * 6,
+        name="softdtw_bwd",
         interpret=interpret,
     )(dd, rd)[..., ::-1]

@@ -514,7 +514,14 @@ class StreamStats:
     """Continuous-batching counters; conservation invariant (checked by
     ``tests/traffic.py``): every submitted request lands in exactly one
     terminal bucket — ``enqueued == served + failed + shed + expired +
-    quarantined + pending``."""
+    quarantined + pending``.
+
+    ``host_syncs`` counts the serving loop's blocking device->host reads
+    (each attempt's ``block_until_ready``, the finiteness check, the
+    trajectory copy, probe reads); eviction reads are
+    ``StoreStats.evictions``.  ``started`` and ``queue_wait_s`` count a
+    request once, at its first assembly: ``queue_wait_s`` sums
+    ``now - t_arrival`` on the caller's clock (``submit``/``pump``)."""
     enqueued: int = 0
     served: int = 0
     failed: int = 0
@@ -525,6 +532,9 @@ class StreamStats:
     twin_steps: int = 0      # real (unpadded) RK4 steps served
     padded_steps: int = 0    # ragged-horizon + batch padding overhead
     splits: int = 0          # requests split across serving windows
+    host_syncs: int = 0      # blocking device->host reads
+    started: int = 0         # requests assembled for the first time
+    queue_wait_s: float = 0.0   # their summed wait from arrival
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -834,6 +844,15 @@ class StreamingFleetServer:
         H = min(self.max_window, -(-h_max // q) * q)
         return picked, H
 
+    def _count_starts(self, picked, now: float) -> None:
+        """Count each request at its first assembly, with its wait since
+        arrival; split continuations were counted when they started."""
+        s = self.stream_stats
+        for r in picked:
+            if r.remaining == r.horizon:
+                s.started += 1
+                s.queue_wait_s += now - r.t_arrival
+
     # -- window programs -----------------------------------------------------
     def _window_fn(self, tier_idx: int, H: int):
         """The jitted fixed-shape window solve of one tier: carried
@@ -888,12 +907,14 @@ class StreamingFleetServer:
         drive_family = self._tiers[tier_idx][1].drive_family
         fn = self._window_fn(tier_idx, H)
         if isinstance(backend, FusedPallasBackend):
-            uh = backend._u_half_window(state, self.t0, self.dt, H,
-                                        starts, drive_family, thetas)
-            if uh.ndim == 2 and uh.shape[-1] > 0:
-                uh = jnp.broadcast_to(uh, (ys.shape[0],) + uh.shape)
+            with jax.profiler.TraceAnnotation("solve.drive"):
+                uh = backend._u_half_window(state, self.t0, self.dt, H,
+                                            starts, drive_family, thetas)
+                if uh.ndim == 2 and uh.shape[-1] > 0:
+                    uh = jnp.broadcast_to(uh, (ys.shape[0],) + uh.shape)
             return fn(ys, uh)
-        tss = ops.window_times(self.t0, self.dt, H, starts)
+        with jax.profiler.TraceAnnotation("solve.drive"):
+            tss = ops.window_times(self.t0, self.dt, H, starts)
         if drive_family is None:
             return fn(ys, tss)
         return fn(ys, tss, thetas)
@@ -916,6 +937,7 @@ class StreamingFleetServer:
         ref = np.asarray(ref_backend.rollout_batch_resumed(
             ref_state, yp, dt=self.dt, num_steps=h, t0=self.t0,
             start_steps=sp, drive_family=drive_family, drive_params=tp))
+        self.stream_stats.host_syncs += 1
         scale = float(np.max(np.abs(ref))) + 1e-9
         prev, chosen = self._active, len(self._tiers) - 1
         for i, (name, tier_fleet) in enumerate(self._tiers[:-1]):
@@ -923,6 +945,7 @@ class StreamingFleetServer:
                 self._states[i], yp, dt=self.dt, num_steps=h, t0=self.t0,
                 start_steps=sp,
                 drive_family=tier_fleet.drive_family, drive_params=tp))
+            self.stream_stats.host_syncs += 1
             err = float(np.max(np.abs(out - ref))) / scale
             self.serving_stats.probe_errors[name] = err
             if np.isfinite(err) and err <= s.max_rel_error:
@@ -990,8 +1013,10 @@ class StreamingFleetServer:
             try:
                 chaos.fault_point("pump:run_tier")
                 t_start = time.perf_counter()
-                out = jax.block_until_ready(
-                    self._run_tier(tier_idx, ys, starts, thetas, H))
+                with jax.profiler.TraceAnnotation("pump.solve"):
+                    out = jax.block_until_ready(
+                        self._run_tier(tier_idx, ys, starts, thetas, H))
+                self.stream_stats.host_syncs += 1
                 if (s is not None and s.timeout_s is not None
                         and time.perf_counter() - t_start > s.timeout_s):
                     self.serving_stats.timeouts += 1
@@ -1024,7 +1049,10 @@ class StreamingFleetServer:
                     raise
                 diags.append(f"{name}: raised {type(e).__name__}: {e}")
                 continue
-            if bool(jnp.isfinite(out[:n]).all()):
+            with jax.profiler.TraceAnnotation("pump.check"):
+                finite = bool(jnp.isfinite(out[:n]).all())
+            self.stream_stats.host_syncs += 1
+            if finite:
                 if i > first:
                     self.serving_stats.nan_rescues += 1
                 return out, i, diags
@@ -1039,7 +1067,9 @@ class StreamingFleetServer:
         pump and journal replay — which is what makes replay reproduce
         the crash-free state transition exactly."""
         tier_name = self._tiers[tier_idx][0]
-        traj_h = np.asarray(traj[:n], np.float32)
+        with jax.profiler.TraceAnnotation("commit.copy_out"):
+            traj_h = np.asarray(traj[:n], np.float32)
+        self.stream_stats.host_syncs += 1
         served = [min(r.remaining, H) for r in picked]
         end_states = traj[jnp.arange(n), jnp.asarray(served)]
         self.store.commit(ids, end_states,
@@ -1052,24 +1082,25 @@ class StreamingFleetServer:
         self.serving_stats.served_by[tier_name] = (
             self.serving_stats.served_by.get(tier_name, 0) + 1)
         done = []
-        for i, req in enumerate(picked):
-            h = served[i]
-            rows = traj_h[i, : h + 1]
-            blocks = self._partial.setdefault(req.seq, [])
-            blocks.append(rows if not blocks else rows[1:])
-            if h < req.remaining:
-                # Long request: re-queue the remainder at the FRONT so
-                # it stays ahead of the twin's later requests.
-                self.stream_stats.splits += 1
-                self._queue.insert(0, dataclasses.replace(
-                    req, remaining=req.remaining - h))
-                continue
-            full = np.concatenate(self._partial.pop(req.seq), axis=0)
-            done.append(Completed(
-                seq=req.seq, twin_id=req.twin_id, trajectory=full,
-                start_step=int(starts[i]) - (req.horizon - h),
-                tier=tier_name, t_arrival=req.t_arrival, t_done=now))
-            self.stream_stats.served += 1
+        with jax.profiler.TraceAnnotation("commit.stitch"):
+            for i, req in enumerate(picked):
+                h = served[i]
+                rows = traj_h[i, : h + 1]
+                blocks = self._partial.setdefault(req.seq, [])
+                blocks.append(rows if not blocks else rows[1:])
+                if h < req.remaining:
+                    # Long request: re-queue the remainder at the FRONT
+                    # so it stays ahead of the twin's later requests.
+                    self.stream_stats.splits += 1
+                    self._queue.insert(0, dataclasses.replace(
+                        req, remaining=req.remaining - h))
+                    continue
+                full = np.concatenate(self._partial.pop(req.seq), axis=0)
+                done.append(Completed(
+                    seq=req.seq, twin_id=req.twin_id, trajectory=full,
+                    start_step=int(starts[i]) - (req.horizon - h),
+                    tier=tier_name, t_arrival=req.t_arrival, t_done=now))
+                self.stream_stats.served += 1
         return done
 
     def pump(self, now: float = 0.0) -> list:
@@ -1087,14 +1118,17 @@ class StreamingFleetServer:
         return done
 
     def _pump(self, now: float) -> list:
-        self._expire(now)
-        picked, H = self._assemble()
+        with jax.profiler.TraceAnnotation("pump.assemble"):
+            self._expire(now)
+            picked, H = self._assemble()
+            self._count_starts(picked, now)
         if not picked:
             if self._journal is not None:
                 self._journal.sync()    # flush any expire records
             return []
         ids = [r.twin_id for r in picked]
-        ys, starts, thetas, n = self._fetch_padded(ids)
+        with jax.profiler.TraceAnnotation("pump.fetch"):
+            ys, starts, thetas, n = self._fetch_padded(ids)
         s = self.slo
         if (s is not None and len(self._tiers) > 1
                 and self.stream_stats.batches % s.probe_every == 0):
@@ -1118,11 +1152,12 @@ class StreamingFleetServer:
             if self._journal is not None:
                 self._journal.append(
                     {"t": "quarantine", "seqs": [r.seq for r in picked],
-                     "reason": reason}, sync=False)
+                     "reason": reason, "now": now}, sync=False)
                 self._journal.sync()
             return []
-        done = self._commit_batch(picked, ids, traj, starts, n, H,
-                                  tier_idx, now)
+        with jax.profiler.TraceAnnotation("pump.commit"):
+            done = self._commit_batch(picked, ids, traj, starts, n, H,
+                                      tier_idx, now)
         if self._journal is not None:
             self._journal.append(
                 {"t": "commit", "seqs": [r.seq for r in picked],
@@ -1282,7 +1317,9 @@ class StreamingFleetServer:
             self.stream_stats.expired += len(rec["seqs"])
             return []
         if t == "quarantine":
-            for req in self._drop_seqs(rec["seqs"]):
+            dropped = self._drop_seqs(rec["seqs"])
+            self._count_starts(dropped, float(rec.get("now", 0.0)))
+            for req in dropped:
                 self.stream_stats.quarantined += 1
                 self._partial.pop(req.seq, None)
                 self.quarantine[req.seq] = Quarantined(
@@ -1314,9 +1351,11 @@ class StreamingFleetServer:
             raise ValueError(
                 "recover: replayed window disagrees with the journalled "
                 "served step counts — scheduler state diverged")
+        self._count_starts(picked, float(rec.get("now", 0.0)))
         self.stream_stats.batches += 1
         traj = jax.block_until_ready(
             self._run_tier(tier_idx, ys, starts, thetas, H))
+        self.stream_stats.host_syncs += 2     # the wait and the check
         if not bool(jnp.isfinite(traj[:n]).all()):
             raise ValueError(
                 "recover: a journalled commit re-executed to non-finite "

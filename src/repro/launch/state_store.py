@@ -45,7 +45,8 @@ class StoreStats:
     registered: int = 0
     hot_hits: int = 0        # fetches served from the hot slab
     page_ins: int = 0        # cold -> hot promotions
-    evictions: int = 0       # hot -> cold LRU pagings
+    evictions: int = 0       # hot -> cold LRU pagings (one device->host
+                             # read each)
     commits: int = 0         # state writes after served batches
 
     def as_dict(self) -> dict:
@@ -151,20 +152,21 @@ class TwinStateStore:
                 f"{self.hot_capacity}")
         pinned = set(ids)
         page_in = []                           # (slot, host_row) pairs
-        for twin_id in ids:
-            if twin_id in self._slot_of:
-                self.stats.hot_hits += 1
-                self._slot_of.move_to_end(twin_id)    # touch: now MRU
-            else:
-                slot = (self._free.pop() if self._free
-                        else self._evict_lru(pinned))
-                page_in.append((slot, self._cold.pop(twin_id)))
-                self._slot_of[twin_id] = slot
-                self.stats.page_ins += 1
-        if page_in:
-            slots = jnp.asarray([s for s, _ in page_in], jnp.int32)
-            rows = jnp.asarray(np.stack([r for _, r in page_in]))
-            self._hot = self._hot.at[slots].set(rows)
+        with jax.profiler.TraceAnnotation("store.page"):
+            for twin_id in ids:
+                if twin_id in self._slot_of:
+                    self.stats.hot_hits += 1
+                    self._slot_of.move_to_end(twin_id)    # touch: now MRU
+                else:
+                    slot = (self._free.pop() if self._free
+                            else self._evict_lru(pinned))
+                    page_in.append((slot, self._cold.pop(twin_id)))
+                    self._slot_of[twin_id] = slot
+                    self.stats.page_ins += 1
+            if page_in:
+                slots = jnp.asarray([s for s, _ in page_in], jnp.int32)
+                rows = jnp.asarray(np.stack([r for _, r in page_in]))
+                self._hot = self._hot.at[slots].set(rows)
         gather = jnp.asarray([self._slot_of[i] for i in ids], jnp.int32)
         ys = self._hot[gather]
         steps = np.asarray([self._step[i] for i in ids], np.int64)
