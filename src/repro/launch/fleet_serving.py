@@ -519,7 +519,7 @@ class StreamStats:
     ``host_syncs`` counts the serving loop's blocking device->host reads
     (each attempt's ``block_until_ready``, the finiteness check, the
     trajectory copy, probe reads); eviction reads are
-    ``StoreStats.evictions``.  ``started`` and ``queue_wait_s`` count a
+    ``StoreStats.evict_reads``.  ``started`` and ``queue_wait_s`` count a
     request once, at its first assembly: ``queue_wait_s`` sums
     ``now - t_arrival`` on the caller's clock (``submit``/``pump``)."""
     enqueued: int = 0

@@ -11,9 +11,10 @@ in device memory next to the serving kernels, so the store is two-level:
     indexed write (no per-twin device round-trips on the serving path).
   * **cold pages** — plain NumPy host arrays, one per twin.  Eviction is
     LRU over the hot slot order: promoting into a full slab pages the
-    least-recently-used resident twin's row back to host FIRST, then
-    reuses its slot — state is never dropped, only moved (the invariant
-    ``tests/traffic.py`` checks after every stress schedule).
+    least-recently-used resident twins' rows back to host FIRST (one
+    device->host read per fetch), then reuses their slots — state is
+    never dropped, only moved (the invariant ``tests/traffic.py`` checks
+    after every stress schedule).
 
 Metadata (global step index, per-twin drive parameters) always lives on
 the host: steps parameterise the canonical float64 time grid
@@ -45,8 +46,9 @@ class StoreStats:
     registered: int = 0
     hot_hits: int = 0        # fetches served from the hot slab
     page_ins: int = 0        # cold -> hot promotions
-    evictions: int = 0       # hot -> cold LRU pagings (one device->host
-                             # read each)
+    evictions: int = 0       # hot -> cold LRU pagings (rows)
+    evict_reads: int = 0     # device->host reads that paged them out:
+                             # one per fetch that evicts
     commits: int = 0         # state writes after served batches
 
     def as_dict(self) -> dict:
@@ -111,22 +113,36 @@ class TwinStateStore:
         self.stats.registered += 1
 
     # -- paging ------------------------------------------------------------
-    def _evict_lru(self, pinned: set) -> int:
-        """Page the least-recently-used unpinned hot twin to host and
-        return its freed slot.  The device row is copied out BEFORE the
-        slot is handed over — eviction moves state, never loses it."""
+    def _evict_lru(self, pinned: set, evicted: list) -> int:
+        """Take the least-recently-used unpinned hot twin's slot, and
+        record ``(twin_id, slot)`` on ``evicted`` for
+        :meth:`_page_out`."""
         chaos.kill_point("store:evict")
         for twin_id in self._slot_of:          # iteration order = LRU
             if twin_id not in pinned:
                 slot = self._slot_of.pop(twin_id)
-                self._cold[twin_id] = np.asarray(self._hot[slot],
-                                                 np.float32)
-                self.stats.evictions += 1
+                evicted.append((twin_id, slot))
                 return slot
         raise RuntimeError(
             f"TwinStateStore: cannot evict — all {self.hot_capacity} hot "
             f"slots are pinned by the current batch (batch larger than "
             f"hot_capacity?)")
+
+    def _page_out(self, evicted: list) -> None:
+        """Copy the evicted twins' device rows to host pages in one
+        gather and one device->host read.  The index vector is padded to
+        the next power of two (repeating the last slot), so eviction
+        counts share at most ceil(log2(hot_capacity)) + 1 compiled
+        gathers."""
+        k = len(evicted)
+        slots = [s for _, s in evicted]
+        slots += slots[-1:] * ((1 << (k - 1).bit_length()) - k)
+        rows = np.asarray(self._hot[jnp.asarray(slots, jnp.int32)],
+                          np.float32)
+        for (twin_id, _), row in zip(evicted, rows[:k]):
+            self._cold[twin_id] = row
+        self.stats.evictions += k
+        self.stats.evict_reads += 1
 
     def fetch(self, twin_ids: Sequence[TwinId]):
         """Promote ``twin_ids`` to the hot slab and gather their state.
@@ -137,6 +153,10 @@ class TwinStateStore:
         (or None if none of the twins carries one).  All requested twins
         are pinned for the duration of the promotion, so a fetch of more
         than ``hot_capacity`` twins raises instead of thrashing.
+
+        Twins evicted to make room are paged out together: their device
+        rows are copied to host BEFORE the page-in scatter overwrites
+        their slots — eviction moves state, never loses it.
         """
         ids = list(twin_ids)
         unknown = [i for i in ids if i not in self]
@@ -152,6 +172,7 @@ class TwinStateStore:
                 f"{self.hot_capacity}")
         pinned = set(ids)
         page_in = []                           # (slot, host_row) pairs
+        evicted = []                           # (twin_id, slot) pairs
         with jax.profiler.TraceAnnotation("store.page"):
             for twin_id in ids:
                 if twin_id in self._slot_of:
@@ -159,10 +180,12 @@ class TwinStateStore:
                     self._slot_of.move_to_end(twin_id)    # touch: now MRU
                 else:
                     slot = (self._free.pop() if self._free
-                            else self._evict_lru(pinned))
+                            else self._evict_lru(pinned, evicted))
                     page_in.append((slot, self._cold.pop(twin_id)))
                     self._slot_of[twin_id] = slot
                     self.stats.page_ins += 1
+            if evicted:
+                self._page_out(evicted)
             if page_in:
                 slots = jnp.asarray([s for s, _ in page_in], jnp.int32)
                 rows = jnp.asarray(np.stack([r for _, r in page_in]))

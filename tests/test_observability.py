@@ -139,7 +139,8 @@ def test_breakdown_names_the_innermost_program_span():
 def test_host_syncs_three_per_pump():
     """With one tier and no retries a pump reads from the device three
     times: the solve's wait, the finiteness check and the trajectory
-    copy.  Eviction reads are counted by the store."""
+    copy.  Eviction reads are counted by the store: one a pump that
+    evicts, whatever the number of rows."""
     server = _server()
     for k, lo in enumerate((0, 4, 8, 0)):
         before = server.stats()
@@ -148,6 +149,8 @@ def test_host_syncs_three_per_pump():
         assert after.stream.host_syncs - before.stream.host_syncs == 3
         assert (after.store.evictions - before.store.evictions
                 == (0 if k == 0 else 4))
+        assert (after.store.evict_reads - before.store.evict_reads
+                == (0 if k == 0 else 1))
 
 
 def test_queue_wait_counts_first_assembly_only():

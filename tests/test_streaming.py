@@ -10,7 +10,9 @@ within one storage rounding for bf16_f32acc.  The hypothesis suite
 samples random split points when hypothesis is installed; a seeded
 parametrised subset always runs.
 """
+import dataclasses
 import functools
+from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
@@ -210,6 +212,89 @@ def test_store_commit_round_trips_state():
     y2, step2 = store.peek("a")
     np.testing.assert_array_equal(y2, y)
     assert step2 == 5
+
+
+def _per_row_reference(store, ids):
+    """The slot each twin of ``ids`` gets, in LRU order, and the
+    ``(victim, slot)`` evictions, as a per-twin promotion loop assigns
+    them (free slots first, then least-recently-used unpinned twins)."""
+    slot_of, free, evicted = OrderedDict(store._slot_of), list(store._free), []
+    for t in ids:
+        if t in slot_of:
+            slot_of.move_to_end(t)
+            continue
+        if free:
+            slot = free.pop()
+        else:
+            victim = next(v for v in slot_of if v not in ids)
+            slot = slot_of.pop(victim)
+            evicted.append((victim, slot))
+        slot_of[t] = slot
+    return list(slot_of.items()), evicted
+
+
+def _bits(a):
+    return np.asarray(a, np.float32).view(np.uint32)
+
+
+@pytest.mark.parametrize("ids,k", [
+    ([1, 4, 5, 6, 7, 8], 3),     # a hot hit, two free slots, 3 evictions
+    ([6, 7, 8, 9], 4),           # evictions only
+    ([3, 1], 0),                 # hot hits only: no read
+])
+def test_store_fetch_pages_out_in_one_read(ids, k):
+    """One fetch that evicts k rows reads the device once: every paged
+    row is the slab row from before the fetch, bit for bit, and the LRU
+    order and slots are what a per-twin loop gives."""
+    store = TwinStateStore(2, hot_capacity=6)
+    for i in range(10):
+        store.register(i, np.float32([i, -i]))
+    store.fetch([0, 1, 2, 3])                 # 2 slots stay free
+    store.commit([0, 1, 2, 3], np.float32([[.1, .2], [.3, .4], [.5, .6],
+                                           [.7, .8]]) * 3, np.arange(4))
+    if k == 4:
+        store.fetch([4, 5])                   # fill the free slots
+    slab = np.asarray(store._hot).copy()
+    want_slots, want_evicted = _per_row_reference(store, ids)
+    assert len(want_evicted) == k
+    cold_in = {t: store.peek(t)[0].copy() for t in ids
+               if t not in store.hot_ids}
+    before = dataclasses.replace(store.stats)
+    store.fetch(ids)
+    assert store.stats.evictions - before.evictions == k
+    assert store.stats.evict_reads - before.evict_reads == (1 if k else 0)
+    assert list(store._slot_of.items()) == want_slots
+    for victim, slot in want_evicted:
+        np.testing.assert_array_equal(_bits(store._cold[victim]),
+                                      _bits(slab[slot]))
+    for t, y in cold_in.items():              # page-ins landed in place
+        np.testing.assert_array_equal(_bits(store.peek(t)[0]), _bits(y))
+    store.check_invariants()
+
+
+def test_store_evictions_in_one_bucket_compile_once():
+    """Fetches evicting 5 and then 6 rows, with every other shape the
+    same (8 ids, 2 hot hits, 6 page-ins), share the padded gather: the
+    second adds no backend compile."""
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    store = TwinStateStore(2, hot_capacity=10)
+    for i in range(30):
+        store.register(i, np.float32([i, i]))
+    store.fetch(range(9))                     # 1 slot stays free
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        store.fetch([0, 1, *range(9, 15)])    # 1 free slot + 5 evictions
+        first = len(compiles)
+        store.fetch([0, 1, *range(15, 21)])   # 6 evictions
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert store.stats.evictions == 11 and store.stats.evict_reads == 2
+    assert first > 0 and len(compiles) == first
 
 
 def test_store_rejects_bad_usage():
