@@ -7,6 +7,10 @@ load, and the comparison each makes with the plain reference.
                     population, latency from each request's due time
     fit_chunks      fit()'s scan engine, chunks back to back
 
+A load not named here is the ``load`` function of ``loads/<load>.py``,
+with the same signature (``resolve_load``).  Whatever depends on the
+vector field comes from the configuration's twin kind (``twin_kind``).
+
 A load builds the system from the configuration and the seed, warms
 every shape its traffic uses (set-up), runs the window, reads the device
 memory peak, frees the program's state, and only then runs the reference.
@@ -27,7 +31,7 @@ import time
 
 import numpy as np
 
-from . import costs, reference, trace, weights, yardstick
+from . import reference, spec, trace, yardstick
 
 CHECK_BLOCK = 4096          # reference rows per compiled call
 
@@ -105,14 +109,15 @@ def rms_rel_err(served: np.ndarray, ref: np.ndarray,
     return float(np.sqrt((diff ** 2).sum() / max((scale ** 2).sum(), 1e-300)))
 
 
-def reference_rollouts(params, y0s: np.ndarray, u_half: np.ndarray,
+def reference_rollouts(field, params, y0s: np.ndarray, u_half: np.ndarray,
                        dt: float, steps: int, operand_dtype=None):
-    """The reference over many windows, CHECK_BLOCK rows per call (the
-    last block padded), at ``highest`` matmul precision."""
+    """The reference of the twin kind's ``field`` over many windows,
+    CHECK_BLOCK rows per call (the last block padded), at ``highest``
+    matmul precision."""
     import jax
     import jax.numpy as jnp
     fn = jax.jit(lambda y, u: reference.rk4_rollout(
-        params, y, u, dt, steps, operand_dtype))
+        field, params, y, u, dt, steps, operand_dtype))
     out = []
     for lo in range(0, y0s.shape[0], CHECK_BLOCK):
         y, u = y0s[lo:lo + CHECK_BLOCK], u_half[lo:lo + CHECK_BLOCK]
@@ -125,27 +130,30 @@ def reference_rollouts(params, y0s: np.ndarray, u_half: np.ndarray,
     return np.concatenate(out)
 
 
-def _sine_half_steps(thetas: np.ndarray, dt: float, starts: np.ndarray,
-                     steps: int) -> np.ndarray:
-    import jax
-    import jax.numpy as jnp
-    t = reference.half_step_times(dt, starts, steps)
-    u = jax.jit(jax.vmap(jax.vmap(yardstick.sine_drive, (0, None))))(
-        jnp.asarray(t), jnp.asarray(thetas, jnp.float32))
-    return np.asarray(u, np.float32)[..., None]
+def twin_kind(config: dict):
+    """The module of the configuration's twin kind, ``twins/<kind>.py``
+    (``mlp`` where the configuration names none)."""
+    return spec.load_module("twins", config.get("twin", "mlp"))
 
 
-def _served_backend(config: dict):
+def resolve_load(name: str):
+    """The load a traffic file names: one of ``LOADS``, or else the
+    ``load`` function of ``loads/<name>.py``."""
+    return LOADS[name] if name in LOADS else spec.load_module(
+        "loads", name).load
+
+
+def served_backend(config: dict):
     from repro.core.backends import FusedPallasBackend
     return FusedPallasBackend(batch_tile=config["batch_tile"],
                               precision=config["precision"])
 
 
-def _check_sizes(twin, config: dict) -> None:
+def check_sizes(twin, kind, config: dict) -> None:
     sizes = tuple(twin.field.sizes)
-    if sizes != weights.layer_sizes(config):
+    if sizes != tuple(kind.layer_sizes(config)):
         raise RuntimeError(f"the program's twin has layer sizes {sizes}, the "
-                           f"configuration {weights.layer_sizes(config)}")
+                           f"configuration {kind.layer_sizes(config)}")
 
 
 # ---------------------------------------------------------------------------
@@ -154,52 +162,14 @@ def _check_sizes(twin, config: dict) -> None:
 
 def _build_stream(config: dict, traffic: dict, params):
     from repro.launch.fleet_serving import StreamingFleetServer
-    backend = _served_backend(config)
-    if config.get("drive_dim", 0):
-        from repro.core.twin import TwinFleet, make_driven_twin
-        drive = config["drive"]
-        twin = make_driven_twin(
-            config["state_dim"],
-            lambda t: yardstick.sine_drive(t, (drive["amp"], drive["freq"])),
-            hidden=config["hidden"],
-            n_hidden_layers=config["n_hidden_layers"])
-        fleet = TwinFleet(twin.with_backend(backend),
-                          drive_family=yardstick.sine_drive)
-    else:
-        from repro.train import recipes
-        fleet = recipes.make_l96_fleet(backend=backend)
-    _check_sizes(fleet.twin, config)
+    kind = twin_kind(config)
+    fleet = kind.served_fleet(config, served_backend(config))
+    check_sizes(fleet.twin, kind, config)
     server = StreamingFleetServer(
         fleet, params, dt=config["dt"], hot_capacity=traffic["hot_capacity"],
         max_batch=traffic["max_batch"], max_window=traffic["max_window"],
         horizon_quantum=traffic["horizon_quantum"], transient_retries=0)
     return server
-
-
-def _initial_states(config: dict, n: int, jax_seed: int):
-    """Seeded initial states (and drive parameters) of n twins."""
-    import jax
-    import jax.numpy as jnp
-    D = config["state_dim"]
-
-    @jax.jit
-    def draw(key):
-        ky, ka, kf = jax.random.split(key, 3)
-        if "y0_spread" in config:
-            y = config["y0_spread"] * jax.random.normal(ky, (n, D))
-        else:
-            lo, hi = config["y0_range"]
-            y = lo + (hi - lo) * jax.random.uniform(ky, (n, D))
-        if not config.get("drive_dim", 0):
-            return y, jnp.zeros((n, 0))
-        d = config["drive"]
-        lo, hi = d["spread"]
-        amp = d["amp"] * (lo + (hi - lo) * jax.random.uniform(ka, (n,)))
-        freq = d["freq"] * (lo + (hi - lo) * jax.random.uniform(kf, (n,)))
-        return y, jnp.stack([amp, freq], axis=-1)
-
-    y, th = draw(jax.random.fold_in(jax.random.PRNGKey(jax_seed), 1))
-    return np.asarray(y, np.float32), np.asarray(th, np.float32)
 
 
 class StreamLedger:
@@ -263,11 +233,9 @@ def _stream_checks(ledger: StreamLedger, params, config: dict,
     for i, k in enumerate(ledger.kept):
         served[i, :k[2] + 1] = k[3]
     y0 = served[:, 0]
-    if config.get("drive_dim", 0):
-        u = _sine_half_steps(thetas[ids], config["dt"], starts, T)
-    else:
-        u = np.zeros((len(ids), 2 * T + 1, 0), np.float32)
-    ref = reference_rollouts(params, y0, u, config["dt"], T)
+    kind = twin_kind(config)
+    u = kind.drive_half_steps(config, thetas[ids], starts, T)
+    ref = reference_rollouts(kind.field, params, y0, u, config["dt"], T)
     checks = {"max_rel_err": float(window_rel_err(served, ref, lens).max()),
               "rms_rel_err": rms_rel_err(served, ref, lens),
               "chain_breaks": ledger.breaks,
@@ -277,7 +245,7 @@ def _stream_checks(ledger: StreamLedger, params, config: dict,
             "longest_compared": T}
     ctl = {}
     if control:
-        low = reference_rollouts(params, y0, u, config["dt"], T,
+        low = reference_rollouts(kind.field, params, y0, u, config["dt"], T,
                                  control_operand_dtype(config))
         ctl = {"max_rel_err": float(window_rel_err(low, ref, lens).max()),
                "rms_rel_err": rms_rel_err(low, ref, lens)}
@@ -289,22 +257,37 @@ def _storage_dtype(precision: str):
     return jnp.float32 if precision == "f32" else jnp.bfloat16
 
 
+def counter_deltas(s0: dict, s1: dict, groups=("stream", "store")) -> dict:
+    """``"<group>.<field>"`` -> the window's change of every numeric field
+    of the server's ``stats().as_dict()`` groups, read with ``.get`` so
+    that a field an older program lacks is simply absent."""
+    out = {}
+    for grp in groups:
+        a, b = s0.get(grp) or {}, s1.get(grp) or {}
+        for k, v in b.items():
+            if (isinstance(v, (int, float)) and not isinstance(v, bool)
+                    and isinstance(a.get(k), (int, float))):
+                out[f"{grp}.{k}"] = v - a[k]
+    return out
+
+
 def _stream_layer(config: dict, traffic: dict, stats0, stats1, pump_s,
                   window: float, chips: int = 1) -> dict:
     s0, s1 = stats0.as_dict(), stats1.as_dict()
     d = lambda grp, k: s1[grp][k] - s0[grp][k]
     twin_steps, padded = d("stream", "twin_steps"), d("stream", "padded_steps")
     page_ins, hits = d("store", "page_ins"), d("store", "hot_hits")
-    sizes = weights.layer_sizes(config)
     return {"twin_steps": twin_steps,
             "twin_steps_per_s": twin_steps / window,
-            "flops_per_twin_step": costs.rk4_step_flops(sizes),
+            "flops_per_twin_step": twin_kind(config).flops_per_twin_step(
+                config),
             "padded_frac": (100.0 * padded / (twin_steps + padded)
                             if twin_steps + padded else None),
             "page_in_share": (100.0 * page_ins / (page_ins + hits)
                               if page_ins + hits else None),
             "pump_ms": 1e3 * float(np.mean(pump_s)) if pump_s else None,
-            "pumps": len(pump_s), "chips": chips}
+            "pumps": len(pump_s), "chips": chips,
+            "counters": counter_deltas(s0, s1)}
 
 
 # ---------------------------------------------------------------------------
@@ -314,9 +297,10 @@ def _stream_layer(config: dict, traffic: dict, stats0, stats1, pump_s,
 def stream_closed(config, traffic, seed, seconds, traced, clock, control=False):
     import jax
     rng, jax_seed = yardstick.seeds(seed)
-    params = weights.make_weights(config, jax_seed)
+    kind = twin_kind(config)
+    params = kind.make_weights(config, jax_seed)
     n, H = traffic["twins"], traffic["horizon"]
-    y0s, thetas = _initial_states(config, n, jax_seed)
+    y0s, thetas = kind.initial_states(config, n, jax_seed)
     server = _build_stream(config, traffic, params)
     with trace.span("register", traced):
         for i in range(n):
@@ -371,10 +355,9 @@ def stream_closed(config, traffic, seed, seconds, traced, clock, control=False):
         window = clock.close() - t0
     stats1 = server.stats()
     layer = _stream_layer(config, traffic, stats0, stats1, pump_s, window)
-    sizes = weights.layer_sizes(config)
     rows = -(-traffic["max_batch"] // config["batch_tile"]) * config["batch_tile"]
-    layer["kernels"] = {"fused_fwd": costs.fused_fwd_cost(
-        sizes, steps=H, rows=rows, precision=config["precision"])}
+    layer["kernels"] = {"fused_fwd": kind.fused_fwd_cost(config, steps=H,
+                                                         rows=rows)}
     layer["trace"] = out.get("trace")
     mem = memory_peak(jax.devices()[:1])
     served_by = stats1.serving.served_by
@@ -424,10 +407,11 @@ def stream_open(config, traffic, seed, seconds, traced, clock, control=False):
     uncounted, so its last requests still go in full batches."""
     import jax
     rng, jax_seed = yardstick.seeds(seed)
-    params = weights.make_weights(config, jax_seed)
+    kind = twin_kind(config)
+    params = kind.make_weights(config, jax_seed)
     P, B = traffic["population"], traffic["max_batch"]
     H = traffic["max_window"]
-    y0s, thetas = _initial_states(config, P, jax_seed)
+    y0s, thetas = kind.initial_states(config, P, jax_seed)
     server = _build_stream(config, traffic, params)
     driven = thetas.shape[1] > 0
     with trace.span("register", traced):
@@ -589,8 +573,6 @@ def fit_checks(losses, params0, params_n, mu_n, ref):
 def fit_chunks(config, traffic, seed, seconds, traced, clock, control=False):
     import jax
     import jax.numpy as jnp
-    from repro.core.backends import FusedPallasBackend
-    from repro.core.twin import make_autonomous_twin
     from repro.train import trainer
     from repro.train.optimizer import adam
     _, jax_seed = yardstick.seeds(seed)
@@ -601,18 +583,17 @@ def fit_chunks(config, traffic, seed, seconds, traced, clock, control=False):
     S = (ys.shape[0] - 1) // L
     idx = np.arange(S)[:, None] * L + np.arange(L + 1)[None, :]
     ts_seg, ys_seg = ts[idx], ys[idx]
-    twin = make_autonomous_twin(config["state_dim"], hidden=config["hidden"],
-                                n_hidden_layers=config["n_hidden_layers"])
-    _check_sizes(twin, config)
-    backend = FusedPallasBackend(batch_tile=config["batch_tile"],
-                                 precision=config["precision"])
+    kind = twin_kind(config)
+    twin = kind.fit_twin(config)
+    check_sizes(twin, kind, config)
+    backend = served_backend(config)
     loss_fn = trainer.segment_loss_fn(
         twin, ts_seg, ys_seg, traffic["loss"], gamma=traffic["gamma"],
         noise_std=traffic["noise_std"], backend=backend)
     opt = adam(traffic["lr"])
     engine = trainer.make_scan_engine(loss_fn, opt, has_key=True, donate=True)
     init = dict(config, weights=config["fit_init"])
-    params0 = weights.make_weights(init, jax_seed)
+    params0 = kind.make_weights(init, jax_seed)
     key0 = jax.random.fold_in(jax.random.PRNGKey(jax_seed), 2)
     n = traffic["chunk_steps"]
     copy = lambda t: jax.tree_util.tree_map(jnp.copy, t)
@@ -647,7 +628,7 @@ def fit_chunks(config, traffic, seed, seconds, traced, clock, control=False):
     gc.collect()
     ref_fn = jax.jit(
         lambda prm, y, key, odt: reference.fit_reference(
-            prm, y, key, steps=n, dt=config["dt"], lr=traffic["lr"],
+            kind.field, prm, y, key, steps=n, dt=config["dt"], lr=traffic["lr"],
             noise_std=traffic["noise_std"], gamma=traffic["gamma"],
             operand_dtype=odt), static_argnums=(3,))
 
@@ -663,13 +644,9 @@ def fit_chunks(config, traffic, seed, seconds, traced, clock, control=False):
         low = ref(control_operand_dtype(config))
         ctl, _ = fit_checks(np.asarray(low[0]), params0, low[1], low[2], r)
         ctl["nonfinite_losses"] = int((~np.isfinite(np.asarray(low[0]))).sum())
-    sizes = weights.layer_sizes(config)
     steps = chunks * n
-    rows = S
-    fwd = costs.fused_fwd_cost(sizes, steps=L, rows=rows,
-                               precision=config["precision"])
-    bwd = costs.fused_bwd_cost(sizes, steps=L, rows=rows,
-                               precision=config["precision"])
+    fwd = kind.fused_fwd_cost(config, steps=L, rows=S)
+    bwd = kind.fused_bwd_cost(config, steps=L, rows=S)
     layer = {"fit_steps_per_s": steps / window,
              "flops_per_step": fwd[0] + bwd[0],
              "kernels": {"fused_fwd": fwd, "fused_bwd": bwd},

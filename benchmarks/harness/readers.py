@@ -39,3 +39,21 @@ def mfu(ctx, rate: str, flops: str):
         return None
     return (100.0 * ctx[rate] * ctx[flops]
             / (ctx["chips"] * ctx["peak"]["bf16_flops_per_s"]))
+
+
+def span_ms(ctx, name: str):
+    """Mean inclusive milliseconds of the host span ``name`` over its
+    occurrences that start inside the traced window; None where it never
+    opened there."""
+    tr = ctx.get("trace")
+    if tr is None:
+        return None
+    lo, hi = tr["window"]
+    durs = [d for n, s, d in tr["spans"] if n == name and lo <= s < hi]
+    return 1e-6 * sum(durs) / len(durs) if durs else None
+
+
+def counter(ctx, name: str):
+    """The window's change of the program counter ``"<group>.<field>"``
+    (``cells.counter_deltas``), or None where the program has none."""
+    return (ctx.get("counters") or {}).get(name)

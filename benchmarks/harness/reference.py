@@ -1,9 +1,11 @@
 """The plain reference every cell's ``correct`` is decided against.
 
-Straightforward float32 ``jax.numpy``: an RK4 step of the ReLU MLP field
-dy/dt = MLP([u(t), y]), soft-DTW by its anti-diagonal recursion, L1, and
-Adam.  Matmuls run at ``highest`` precision (a TPU otherwise rounds f32
-operands to bf16).  Nothing here imports the program.
+Straightforward float32 ``jax.numpy``: RK4 of a vector field, the ReLU
+MLP field dy/dt = MLP([u(t), y]), soft-DTW by its anti-diagonal
+recursion, L1, and Adam.  Matmuls run at ``highest`` precision (a TPU
+otherwise rounds f32 operands to bf16).  Nothing here imports the
+program.  A field is ``field(params, u, y, operand_dtype) -> dy/dt``;
+each twin kind (``twins/<kind>.py``) names its own.
 
 ``operand_dtype`` puts the same reference one precision step below the
 configuration's, for the control: every matmul operand (activations and
@@ -19,7 +21,7 @@ import numpy as np
 BIG = 1e10
 
 
-def _round(x, operand_dtype):
+def operand_round(x, operand_dtype):
     """x rounded to the mantissa of the float type ``operand_dtype`` and
     back; an 8-bit type is scaled per tensor so that its largest magnitude
     maps to the format's largest finite value, as such arithmetic is used.
@@ -47,35 +49,41 @@ def _round(x, operand_dtype):
 
 def mlp(params, x, operand_dtype=None):
     for i, layer in enumerate(params):
-        x = jnp.dot(_round(x, operand_dtype), _round(layer["w"], operand_dtype),
+        x = jnp.dot(operand_round(x, operand_dtype),
+                    operand_round(layer["w"], operand_dtype),
                     precision=jax.lax.Precision.HIGHEST) + layer["b"]
         if i < len(params) - 1:
             x = jax.nn.relu(x)
     return x
 
 
-def rk4_rollout(params, y0, u_half, dt: float, steps: int,
+def mlp_field(params, u, y, operand_dtype=None):
+    """dy/dt = MLP([u, y]), or MLP(y) where the drive has no channels."""
+    inp = jnp.concatenate([u, y], axis=-1) if u.shape[-1] else y
+    return mlp(params, inp, operand_dtype)
+
+
+def rk4_rollout(field, params, y0, u_half, dt: float, steps: int,
                 operand_dtype=None):
     """(B, D) states and (B, 2*steps+1, Du) half-step drives (Du may be 0)
     -> (B, steps+1, D) trajectories with row 0 = y0."""
-    def field(u, y):
-        inp = jnp.concatenate([u, y], axis=-1) if u.shape[-1] else y
-        return mlp(params, inp, operand_dtype)
+    def f(u, y):
+        return field(params, u, y, operand_dtype)
 
     def step(y, t):
         u0 = u_half[:, 2 * t]
         um = u_half[:, 2 * t + 1]
         u1 = u_half[:, 2 * t + 2]
-        k1 = field(u0, y)
-        k2 = field(um, y + dt / 2 * k1)
-        k3 = field(um, y + dt / 2 * k2)
-        k4 = field(u1, y + dt * k3)
+        k1 = f(u0, y)
+        k2 = f(um, y + dt / 2 * k1)
+        k3 = f(um, y + dt / 2 * k2)
+        k4 = f(u1, y + dt * k3)
         y = y + dt / 6 * (k1 + 2 * k2 + 2 * k3 + k4)
         return y, y
 
     _, ys = jax.lax.scan(step, y0, jnp.arange(steps))
     traj = jnp.concatenate([y0[:, None], jnp.transpose(ys, (1, 0, 2))], axis=1)
-    return _round(traj, operand_dtype)
+    return operand_round(traj, operand_dtype)
 
 
 def half_step_times(dt: float, start_steps: np.ndarray, steps: int):
@@ -117,20 +125,21 @@ def soft_dtw(x, y, gamma: float):
     return last[n - 1]
 
 
-def segment_loss(params, y0s, ys_seg, dt: float, gamma: float,
+def segment_loss(field, params, y0s, ys_seg, dt: float, gamma: float,
                  operand_dtype=None):
     """l1 + 0.1 * mean soft-DTW / (L+1) over the shooting segments."""
     steps = ys_seg.shape[1] - 1
     uh = jnp.zeros((y0s.shape[0], 2 * steps + 1, 0), jnp.float32)
-    preds = rk4_rollout(params, y0s, uh, dt, steps, operand_dtype)
+    preds = rk4_rollout(field, params, y0s, uh, dt, steps, operand_dtype)
     l1 = jnp.mean(jnp.abs(preds - ys_seg))
     sdtw = jnp.mean(jax.vmap(lambda p, t: soft_dtw(p, t, gamma))(preds, ys_seg))
     return l1 + 0.1 * sdtw / ys_seg.shape[1]
 
 
-def fit_reference(params, ys_seg, key, *, steps: int, dt: float, lr: float,
-                  noise_std: float, gamma: float = 0.1, b1: float = 0.9,
-                  b2: float = 0.999, eps: float = 1e-8, operand_dtype=None):
+def fit_reference(field, params, ys_seg, key, *, steps: int, dt: float,
+                  lr: float, noise_std: float, gamma: float = 0.1,
+                  b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
+                  operand_dtype=None):
     """``steps`` Adam steps on the noisy-initial-state segment loss.
 
     Each step splits the key and perturbs the segments' initial states by
@@ -144,8 +153,8 @@ def fit_reference(params, ys_seg, key, *, steps: int, dt: float, lr: float,
         key, sub = jax.random.split(key)
         y0s = ys_seg[:, 0] + noise_std * jax.random.normal(
             sub, ys_seg[:, 0].shape)
-        loss, g = jax.value_and_grad(segment_loss)(
-            p, y0s, ys_seg, dt, gamma, operand_dtype)
+        loss, g = jax.value_and_grad(segment_loss, argnums=1)(
+            field, p, y0s, ys_seg, dt, gamma, operand_dtype)
         mu = tree(lambda m, x: b1 * m + (1 - b1) * x, mu, g)
         nu = tree(lambda v, x: b2 * v + (1 - b2) * x * x, nu, g)
         t = (i + 1).astype(jnp.float32)

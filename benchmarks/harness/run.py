@@ -78,7 +78,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     from . import cells, trace
     from .yardstick import CompileClock
     compiles = CompileClock()
-    load = cells.LOADS[cell.traffic["load"]]
+    load = cells.resolve_load(cell.traffic["load"])
     holder = {}
     clock = cells.Clock(
         on_open=lambda: holder.update(setup=compiles.take()),

@@ -3,17 +3,42 @@
 Everything that belongs to one configuration, one traffic mix, one cell's
 limits or one per-layer metric sits in a file of its own:
 
-    configs/<config>.json         sizes, precision, weights, plain reference
+    configs/<config>.json         sizes, precision, weights, plain reference;
+                                  ``"twin": "<kind>"`` names its twin kind
+    twins/<kind>.py               a twin kind (below); ``mlp`` by default
     traffic/<traffic>.json        the mix: which load and its parameters
+    loads/<load>.py               a load ``cells.LOADS`` lacks, as
+                                  ``load(config, traffic, seed, seconds,
+                                  traced, clock, control=False)``
     limits/<workload>.json        the limit of each number ``correct`` compares
     metrics/<metric>.py           a reader with ``read(ctx) -> float | None``
+    kernels/<kernel>.json         ``{"op": regex}`` over a device op's HLO
+                                  text, beside the rules of ``kernels.json``
 
 so a cell or a metric is added by adding files and entries, never by
 editing one that exists.
+
+A twin kind is everything that depends on the vector field, as module
+functions of ``twins/<kind>.py``:
+
+    layer_sizes(config)                   the program twin's sizes, checked
+    served_fleet(config, backend)         the program's TwinFleet
+    fit_twin(config)                      the program twin fit() trains
+    make_weights(config, jax_seed)        seeded weights, made on the device
+    initial_states(config, n, jax_seed)   (y0s, thetas) as host arrays
+    drive_half_steps(config, thetas, starts, steps)
+                                          the control samples the
+                                          reference reads, (n, 2*steps+1, Du)
+    field(params, u, y, operand_dtype)    the plain f32 reference field,
+                                          operands rounded for the control
+    flops_per_twin_step(config)
+    fused_fwd_cost(config, steps=, rows=), fused_bwd_cost(...)
+                                          (operations, HBM bytes) of a call
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
@@ -71,19 +96,28 @@ def resolve(bench: dict, workload: str) -> Cell:
                 per_layer=per_layer)
 
 
-def metric_path(name: str) -> pathlib.Path:
-    path = HARNESS / "metrics" / f"{name}.py"
+def module_path(folder: str, name: str) -> pathlib.Path:
+    path = HARNESS / folder / f"{name}.py"
     if not path.is_file():
-        raise FileNotFoundError(f"no reader for per-layer metric {name!r}: "
-                                f"{path}")
+        raise FileNotFoundError(f"no {folder}/{name}.py: {path}")
     return path
+
+
+def metric_path(name: str) -> pathlib.Path:
+    return module_path("metrics", name)
+
+
+@functools.cache
+def load_module(folder: str, name: str):
+    """The module ``<folder>/<name>.py``, loaded once."""
+    spec = importlib.util.spec_from_file_location(
+        f"harness_{folder}_{name.replace('.', '_').replace('-', '_')}",
+        module_path(folder, name))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def load_reader(name: str):
     """The ``read`` function of ``metrics/<name>.py``."""
-    spec = importlib.util.spec_from_file_location(
-        f"harness_metric_{name.replace('.', '_').replace('-', '_')}",
-        metric_path(name))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+    return load_module("metrics", name).read

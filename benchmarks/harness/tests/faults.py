@@ -51,6 +51,32 @@ def rows_mixed():
     return patched(FusedPallasBackend, "_solve", bad)
 
 
+def trajectory_frozen():
+    """The solve returns its state unchanged: every row of a window is
+    its first."""
+    from repro.core.backends import FusedPallasBackend
+    solve = FusedPallasBackend._solve
+
+    def bad(self, *a, **k):
+        traj = solve(self, *a, **k)
+        return jnp.broadcast_to(traj[:1], traj.shape)
+    return patched(FusedPallasBackend, "_solve", bad)
+
+
+def exchange_dropped():
+    """The output is never gathered from the twin mesh: every device's
+    rows are the first device's."""
+    from repro.launch.fleet_serving import FleetServer
+    serve = FleetServer.serve
+
+    def bad(self, *a, **k):
+        out = serve(self, *a, **k)
+        m = out.shape[0] // self.n_shards
+        return jnp.concatenate([out[:m]] * self.n_shards
+                               + [out[m * self.n_shards:]])
+    return patched(FleetServer, "serve", bad)
+
+
 def update_skipped():
     """The optimizer step returns the parameters unchanged."""
     from repro.train import trainer
@@ -69,5 +95,6 @@ def half_batch():
 
 
 FAULTS = {"answer_altered": answer_altered, "state_unchanged": state_unchanged,
-          "rows_mixed": rows_mixed, "update_skipped": update_skipped,
-          "half_batch": half_batch}
+          "rows_mixed": rows_mixed, "trajectory_frozen": trajectory_frozen,
+          "exchange_dropped": exchange_dropped,
+          "update_skipped": update_skipped, "half_batch": half_batch}

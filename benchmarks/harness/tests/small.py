@@ -14,6 +14,9 @@ SMALL = {
                                    max_horizon=16)),
     "l96_fit_seg60": (dict(num_points=200, train_points=121),
                       dict(segment=10, chunk_steps=10)),
+    "l96_long_closed_4chip": ({}, dict(fleet=16, horizon=8, devices=1,
+                                       distinct_batches=100,
+                                       warm_batches=1, check_twins=4)),
 }
 
 

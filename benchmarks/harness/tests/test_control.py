@@ -16,6 +16,7 @@ CONTROL_SIZES = {
     "l96_long_closed": dict(horizon=600, max_window=600),
     "hp_telemetry_open": dict(max_window=64, max_horizon=64),
     "l96_fit_seg60": dict(),
+    "l96_long_closed_4chip": dict(horizon=200),
 }
 
 

@@ -1,6 +1,8 @@
 """With the timed path broken underneath, a run's ``correct`` comes out
 false: once for each fault the cell can have.  The chip check is skipped;
-the rest of the run is the benchmark's own."""
+the rest of the run is the benchmark's own.  The sharded cell's exchange
+between chips is broken on four virtual devices
+(``test_rehearsal.test_sharded_cell_on_four_devices``)."""
 import pytest
 
 from benchmarks.harness import run
@@ -14,6 +16,9 @@ CASES = [("l96_long_closed", "answer_altered"),
          ("hp_telemetry_open", "answer_altered"),
          ("hp_telemetry_open", "state_unchanged"),
          ("hp_telemetry_open", "rows_mixed"),
+         ("l96_long_closed_4chip", "answer_altered"),
+         ("l96_long_closed_4chip", "trajectory_frozen"),
+         ("l96_long_closed_4chip", "rows_mixed"),
          ("l96_fit_seg60", "update_skipped"),
          ("l96_fit_seg60", "half_batch")]
 

@@ -9,12 +9,19 @@ from benchmarks.harness import cells, spec
 
 BENCH = spec.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+#: what every twin kind provides (spec.py)
+TWIN_KIND = ("layer_sizes", "served_fleet", "fit_twin", "make_weights",
+             "initial_states", "drive_half_steps", "field",
+             "flops_per_twin_step", "fused_fwd_cost", "fused_bwd_cost")
 
 
 @pytest.mark.parametrize("workload", [w["name"] for w in BENCH["workloads"]])
 def test_workload_resolves_to_its_files(workload):
     cell = spec.resolve(BENCH, workload)
-    assert cell.traffic["load"] in cells.LOADS
+    assert callable(cells.resolve_load(cell.traffic["load"]))
+    kind = cells.twin_kind(cell.config)
+    for fn in TWIN_KIND:
+        assert callable(getattr(kind, fn)), fn
     assert any(m["name"] != "setup_s" for m in cell.end_to_end)
     assert any(m["name"] == "setup_s" for m in cell.end_to_end)
     assert cell.per_layer, "every cell reports a per-layer metric"

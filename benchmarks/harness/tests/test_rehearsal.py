@@ -1,5 +1,6 @@
 """Every cell end to end at a small size on the CPU (Pallas interpreted),
 and the measuring command's refusal to run without a TPU."""
+import json
 import os
 import subprocess
 import sys
@@ -23,7 +24,7 @@ def _check(result, cell):
 
 
 @pytest.mark.parametrize("name", ["l96_long_closed", "hp_telemetry_open",
-                                  "l96_fit_seg60"])
+                                  "l96_fit_seg60", "l96_long_closed_4chip"])
 def test_cell_end_to_end(name):
     cell = small_cell(name)
     result, outcome = run.run_cell(cell, SEED, 0.5, False,
@@ -68,3 +69,33 @@ def test_command_refuses_without_tpu():
     assert out.returncode != 0
     assert out.stdout.strip() == ""
     assert "no TPU" in out.stderr
+
+
+SHARDED = f"""
+import json
+from benchmarks.harness import run
+from benchmarks.harness.tests.faults import FAULTS
+from benchmarks.harness.tests.small import small_cell
+cell = small_cell("l96_long_closed_4chip", devices=4)
+sound, _ = run.run_cell(cell, {SEED}, 0.3, True, device_kind="TPU v5 lite")
+with FAULTS["exchange_dropped"]():
+    broken, _ = run.run_cell(cell, {SEED}, 0.3, False,
+                             device_kind="TPU v5 lite")
+print(json.dumps({{"sound": sound, "broken": broken}}))
+"""
+
+
+def test_sharded_cell_on_four_devices():
+    """The 4-chip cell over four virtual CPU devices: correct and traced,
+    and not correct when only the first device's rows come back (the
+    exchange between chips left out)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=f"{ROOT}{os.pathsep}{ROOT / 'src'}")
+    out = subprocess.run([sys.executable, "-c", SHARDED], cwd=ROOT, env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    got = json.loads(out.stdout.strip().splitlines()[-1])
+    assert got["sound"]["correct"], got["sound"]["checks"]
+    assert "idle_share.sharded" in got["sound"]["metrics"]
+    assert got["broken"]["correct"] is False, got["broken"]["checks"]

@@ -82,3 +82,68 @@ def test_capture_and_reduce_a_live_trace():
     assert [s[0] for s in tr["spans"]] == ["pump"] * 3
     assert any(tr["devices"].values())
     assert 0.0 < trace.idle_share(tr) < 100.0
+
+
+def test_span_ms_and_counters():
+    tr = synthetic()
+    tr["spans"].append(["pump", 120, 40])          # starts past the window
+    ctx = {"trace": tr, "counters": {"stream.batches": 4}}
+    assert readers.span_ms(ctx, "pump") == pytest.approx(50e-6)
+    assert readers.span_ms(ctx, "submit") == pytest.approx(25e-6)
+    assert readers.span_ms(ctx, "pump.solve") is None
+    assert readers.span_ms({"trace": None}, "pump") is None
+    assert readers.counter(ctx, "stream.batches") == 4
+    assert readers.counter(ctx, "store.evict_reads") is None
+    assert readers.counter({}, "stream.batches") is None
+
+
+def test_counter_deltas_read_every_numeric_field():
+    from benchmarks.harness import cells
+    s0 = {"stream": {"batches": 1, "queue_wait_s": 0.5, "flag": False},
+          "serving": {"requests": 1},
+          "store": {"evict_reads": 2, "served_by": {}}}
+    s1 = {"stream": {"batches": 4, "queue_wait_s": 2.0, "flag": True,
+                     "new": 3},
+          "serving": {"requests": 9},
+          "store": {"evict_reads": 5, "served_by": {"a": 1}}}
+    assert cells.counter_deltas(s0, s1) == {
+        "stream.batches": 3, "stream.queue_wait_s": 1.5,
+        "store.evict_reads": 3}
+
+
+def test_program_spans_kept_from_a_live_capture():
+    """The reducer keeps the spans the program opens inside its own
+    calls, by name, with no list of them in the harness."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core.backends import FusedPallasBackend
+    from repro.core.twin import TwinFleet, make_driven_twin
+    from repro.launch.fleet_serving import StreamingFleetServer
+    twin = make_driven_twin(2, lambda t: jnp.sin(t), hidden=8,
+                            n_hidden_layers=1)
+    fleet = TwinFleet(twin.with_backend(FusedPallasBackend(precision="f32")),
+                      drive_family=lambda t, th: th[0] * jnp.sin(t))
+    server = StreamingFleetServer(
+        fleet, twin.init(jax.random.PRNGKey(0)), dt=0.01, hot_capacity=4,
+        max_batch=4, max_window=8, horizon_quantum=4, transient_retries=0)
+    for i in range(8):
+        server.register_twin(i, np.full(2, 0.1, np.float32),
+                             theta=np.float32([1.0]))
+
+    def pump(ids):
+        for i in ids:
+            server.submit(i, 8)
+        server.pump()
+
+    pump(range(4))                                  # compiles
+    out = {}
+    with trace.capture(out):
+        with trace.span("pump", True):
+            pump(range(4, 8))                       # evicts: store.page
+    names = {s[0] for s in out["trace"]["spans"]}
+    assert {"pump", "pump.fetch", "store.page", "pump.solve", "solve.drive",
+            "pump.commit"} <= names
+    assert not names & {"ParseArguments", "PjitFunction(<lambda>)"}
+    assert "store.page" not in trace.SPANS
+    assert readers.span_ms({"trace": out["trace"]}, "store.page") > 0

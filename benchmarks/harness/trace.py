@@ -5,7 +5,14 @@ trace in a plain form (``reduce_xspace``):
 
     {"window": [start_ns, end_ns],              # the harness's "window" span
      "devices": {plane: [[op, start_ns, dur_ns, hlo_module], ...]},
-     "spans":   [[name, start_ns, dur_ns], ...]} # the harness's host spans
+     "spans":   [[name, start_ns, dur_ns], ...]} # every host annotation
+
+``spans`` holds every ``jax.profiler.TraceAnnotation`` the window opened,
+the harness's and the program's alike.  The profiler writes the
+runtime's own host events beside them with nothing to tell the two
+apart, so ``capture`` records the name of each annotation opened while
+it runs, and the reduction keeps the host events of those names (and of
+``SPANS``).
 
 Device ops are the events of each ``/device:TPU:<i>`` plane's "XLA Ops"
 line.  A host without such planes (the CPU rehearsal) contributes the
@@ -33,6 +40,8 @@ SPANS = ("window", "register", "submit", "pump", "engine_chunk",
          "serve_batch")
 OPS_LINE = "XLA Ops"
 KERNELS = pathlib.Path(__file__).resolve().parent / "kernels.json"
+#: One more kernel rule per file, ``<kernel>.json``.
+KERNEL_DIR = KERNELS.with_name("kernels")
 #: Ops that contain other ops of the same line (a scan's loop): they count
 #: toward busy time through the union, not as ops of their own.
 CONTAINERS = ("while", "conditional", "call")
@@ -51,11 +60,19 @@ def capture(out: dict):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = HOST_TRACER_LEVEL
+    real, names = jax.profiler.TraceAnnotation, set()
+
+    def recorded(name, **kwargs):
+        names.add(name)
+        return real(name, **kwargs)
+
     jax.profiler.start_trace(tmp, profiler_options=opts)
+    jax.profiler.TraceAnnotation = recorded
     try:
-        with jax.profiler.TraceAnnotation("window"):
+        with real("window"):
             yield
     finally:
+        jax.profiler.TraceAnnotation = real
         jax.profiler.stop_trace()
         try:
             paths = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"),
@@ -63,7 +80,7 @@ def capture(out: dict):
             if not paths:
                 raise RuntimeError("the profiler wrote no .xplane.pb")
             out["trace"] = reduce_xspace(
-                jax.profiler.ProfileData.from_file(paths[0]))
+                jax.profiler.ProfileData.from_file(paths[0]), names)
         finally:
             shutil.rmtree(tmp, ignore_errors=True)
 
@@ -76,7 +93,10 @@ def span(name: str, traced: bool):
     return jax.profiler.TraceAnnotation(name)
 
 
-def reduce_xspace(pd) -> dict:
+def reduce_xspace(pd, annotations=()) -> dict:
+    """The plain form of a profile: device ops, the window, and the host
+    events named in ``SPANS`` or ``annotations``."""
+    keep = set(SPANS) | set(annotations)
     devices, host_ops, spans, window = {}, [], [], None
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU:"):
@@ -88,7 +108,7 @@ def reduce_xspace(pd) -> dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name in SPANS:
+                    if e.name in keep:
                         if e.name == "window":
                             window = [e.start_ns, e.start_ns + e.duration_ns]
                         else:
@@ -108,11 +128,18 @@ def reduce_xspace(pd) -> dict:
 @functools.cache
 def kernel_rules() -> dict:
     """kernel -> compiled regex over the device op's HLO text, from
-    ``kernels.json``: on a TPU an op event is named by its HLO
-    instruction, and a Pallas kernel is a ``custom-call`` told apart by
-    its operand and result shapes."""
+    ``kernels.json`` and each ``kernels/<kernel>.json``: on a TPU an op
+    event is named by its HLO instruction, and a Pallas kernel is a
+    ``custom-call`` told apart by its operand and result shapes."""
     with open(KERNELS) as f:
-        return {k: re.compile(r["op"]) for k, r in json.load(f).items()}
+        rules = json.load(f)
+    for path in sorted(KERNEL_DIR.glob("*.json")):
+        if path.stem in rules:
+            raise ValueError(f"kernel {path.stem!r} has two rules: "
+                             f"{KERNELS.name} and {path}")
+        with open(path) as f:
+            rules[path.stem] = json.load(f)
+    return {k: re.compile(r["op"]) for k, r in rules.items()}
 
 
 _HLO = re.compile(r"^%\S+ = (.*?) ([a-z][a-z0-9-]*)\(")
