@@ -8,9 +8,11 @@ from repro.core.losses import (dtw, l1, lyapunov_time,
 from repro.core.backends import (AnalogueBackend, Backend, DigitalBackend,
                                  ExecState, FusedPallasBackend,
                                  resolve_backend)
-from repro.core.node import (ContinuousDepthBlock, MLPVectorField, NeuralODE,
-                             dense_linear, mlp_apply, mlp_init)
+from repro.core.node import (CDEVectorField, ContinuousDepthBlock,
+                             MLPVectorField, NeuralODE, dense_linear,
+                             mlp_apply, mlp_init)
 from repro.core.ode import make_odeint, odeint, odeint_dopri5, rk4_step
 from repro.core.twin import (DigitalTwin, TwinFleet, make_autonomous_twin,
-                             make_driven_twin, reference_trajectory,
+                             make_cde_twin, make_driven_twin,
+                             reference_trajectory,
                              simulate_batch)

@@ -47,7 +47,8 @@ from repro.core.analogue import (AnalogueMLPVectorField, AnalogueSpec,
                                  program_mlp_with_verify, stage_uint8)
 from repro.core.faults import FaultModel, apply_faults_to_mlp
 from repro.core.ode import make_odeint, odeint
-from repro.kernels.fused_ode_mlp import DEFAULT_VMEM_BUDGET
+from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET, MLPField,
+                                         rollout_plan)
 
 Pytree = Any
 
@@ -58,6 +59,24 @@ class ExecState(NamedTuple):
     field: Callable          # f(t, y, params) -> dy/dt
     params: Pytree           # pytree threaded to the field, or None
     extra: Any = None        # backend-private staging (e.g. fused operands)
+
+
+def kernel_field(field: Callable):
+    """The fused kernel's form of a vector field (``field.kernel_field()``:
+    :class:`~repro.kernels.fused_ode_mlp.MLPField` or ``CDEField``);
+    ``None`` for a field that names none, which the kernels take as the
+    ReLU MLP of its parameters."""
+    make = getattr(field, "kernel_field", None)
+    return None if make is None else make()
+
+
+def _require_mlp(field: Callable, backend: str) -> None:
+    """The crossbar backends program ReLU-MLP layers; refuse another field
+    instead of serving the wrong one."""
+    kf = kernel_field(field)
+    if kf is not None and not isinstance(kf, MLPField):
+        raise ValueError(f"{backend} programs ReLU-MLP fields onto crossbars,"
+                         f" not {type(field).__name__}")
 
 
 def _with_drive(state: ExecState, drive: Optional[Callable]) -> ExecState:
@@ -305,6 +324,7 @@ class AnalogueBackend(BaseBackend):
     n_reads: int = 0                # drift snapshot: reads already served
 
     def program(self, field: Callable, params: Pytree) -> ExecState:
+        _require_mlp(field, "AnalogueBackend")
         if self.storage not in ("float", "uint8"):
             raise ValueError(
                 f"AnalogueBackend storage={self.storage!r}; have "
@@ -406,13 +426,17 @@ class FusedPallasBackend(BaseBackend):
         the masters — not pre-rounded bf16 copies — keeps the per-call
         ``precision`` override honest: ``precision="f32"`` on a
         bf16-policy backend really is the exact path, and bf16→f32→bf16
-        round-trips cannot double-round."""
+        round-trips cannot double-round.  ``extra["field"]`` is the
+        field's kernel form (:func:`kernel_field`), which the solve hands
+        the kernel: the ReLU MLP, or the neural CDE whose output layer the
+        kernel restages."""
         if params is None:
-            raise ValueError("FusedPallasBackend needs the MLP params")
+            raise ValueError("FusedPallasBackend needs the field's params")
         weights = [p["w"].astype(jnp.float32) for p in params]
         biases = [p["b"].astype(jnp.float32) for p in params]
         return ExecState(field=field, params=params,
-                         extra={"weights": weights, "biases": biases})
+                         extra={"weights": weights, "biases": biases,
+                                "field": kernel_field(field)})
 
     def _grid(self, ts: jax.Array, steps_per_interval: int):
         """Validate + densify the time grid; returns (ts_fine, dt, sub)."""
@@ -480,7 +504,20 @@ class FusedPallasBackend(BaseBackend):
             params, y0s, uh, dt, batch_tile=bt, time_chunk=self.time_chunk,
             interpret=self.interpret,
             vmem_budget_bytes=self.vmem_budget_bytes, gradient=mode,
-            precision=self.precision if precision is None else precision)
+            precision=self.precision if precision is None else precision,
+            field=state.extra.get("field"))
+
+    def time_chunks(self, state: ExecState, ys, uh) -> int:
+        """Time chunks of the detached (serving) solve of the carried
+        states ``ys`` (B, D) over the drive window ``uh``: the forward
+        kernel's own plan (``fused_ode_mlp.rollout_plan``), from shapes
+        alone."""
+        return rollout_plan(
+            *ys.shape, uh.shape, state.extra["weights"],
+            state.extra["biases"], batch_tile=self.batch_tile,
+            time_chunk=self.time_chunk,
+            vmem_budget_bytes=self.vmem_budget_bytes,
+            precision=self.precision, field=state.extra["field"]).num_chunks
 
     def _u_half_window(self, state: ExecState, t0, dt, num_steps,
                        starts: np.ndarray,
@@ -650,6 +687,7 @@ class FusedAnalogueBackend(FusedPallasBackend):
 
     # -- deployment --------------------------------------------------------
     def program(self, field: Callable, params: Pytree) -> ExecState:
+        _require_mlp(field, "FusedAnalogueBackend")
         if self.storage not in ("float", "uint8"):
             raise ValueError(
                 f"FusedAnalogueBackend storage={self.storage!r}; have "
@@ -747,6 +785,12 @@ class FusedAnalogueBackend(FusedPallasBackend):
             vmem_budget_bytes=self.vmem_budget_bytes,
             read_noise=self.spec.read_noise, noise_seed=self.read_seed,
             step_offset=step_offset)
+
+    def time_chunks(self, state: ExecState, ys, uh) -> int:
+        """Not counted: the analogue kernel plans its own conductance
+        operands, and ``StreamStats.time_chunks`` counts the digital
+        fused kernel's."""
+        return 0
 
 
 DEFAULT_BACKEND = DigitalBackend()

@@ -8,6 +8,8 @@ Pieces:
   network can execute digitally (jnp dot), through the analogue-crossbar
   simulator (:mod:`repro.core.analogue`) or through the fused Pallas
   kernel (:mod:`repro.kernels`).
+* ``CDEVectorField`` — the neural CDE field dz/dt = f_θ(z)·dX/dt
+  (Kidger et al. 2020), served as a twin with a per-twin control path.
 * ``NeuralODE`` — ties a vector field to an integrator + gradient mode
   (adjoint vs backprop-through-solver); handles driven systems (external
   input u(t), HP twin) and autonomous systems (Lorenz96 twin).
@@ -25,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.core.backends import Backend, resolve_backend
 from repro.core.ode import odeint
+from repro.kernels.fused_ode_mlp import CDEField, MLPField
 
 Pytree = Any
 
@@ -81,6 +84,51 @@ class MLPVectorField:
         else:
             inp = y
         return mlp_apply(params, inp, self.activation, self.linear_fn)
+
+    def kernel_field(self) -> MLPField:
+        """The fused kernel's form of this field."""
+        return MLPField(len(self.sizes) - 1)
+
+
+@dataclasses.dataclass(frozen=True)
+class CDEVectorField:
+    """Neural CDE field dz/dt = f_θ(z) · dX/dt (Kidger, Morrill, Foster,
+    Lyons, arXiv:2005.08926, the ``FinalTanh`` field): f_θ a ReLU MLP of
+    ``sizes`` = (D, H, ..., H, D·Du) with tanh on its output, read as a
+    (D, Du) matrix and contracted with the control's derivative.
+
+    ``drive(t) -> (Du,)`` is dX/dt, the twin's control derivative (the
+    name lets the backends re-bind it per twin, as they do a driven
+    MLP's input); it is required.
+    """
+    sizes: tuple
+    control_channels: int
+    drive: Optional[Callable[[jax.Array], jax.Array]] = None
+
+    def __post_init__(self):
+        D, Du = self.sizes[0], self.control_channels
+        if self.sizes[-1] != D * Du:
+            raise ValueError(
+                f"CDEVectorField: the output layer must be D·Du = {D * Du} "
+                f"wide for state {D} and {Du} control channels, got "
+                f"{self.sizes[-1]}")
+
+    def init(self, key: jax.Array) -> Pytree:
+        return mlp_init(key, self.sizes)
+
+    def __call__(self, t: jax.Array, z: jax.Array, params: Pytree) -> jax.Array:
+        if self.drive is None:
+            raise ValueError("CDEVectorField needs its control derivative "
+                             "(drive=dX/dt)")
+        dxdt = jnp.asarray(self.drive(t), dtype=z.dtype)
+        g = jnp.tanh(mlp_apply(params, z)).reshape(
+            z.shape[:-1] + (self.sizes[0], self.control_channels))
+        return jnp.einsum("...ic,c->...i", g, dxdt)
+
+    def kernel_field(self) -> CDEField:
+        """The fused kernel's form of this field."""
+        return CDEField(len(self.sizes) - 1, self.sizes[0],
+                        self.control_channels)
 
 
 # ---------------------------------------------------------------------------

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from repro.core.analogue import AnalogueSpec, program_mlp
 from repro.core.backends import (AnalogueBackend, Backend, DigitalBackend,
                                  FusedPallasBackend, resolve_backend)
-from repro.core.node import MLPVectorField, NeuralODE
+from repro.core.node import CDEVectorField, MLPVectorField, NeuralODE
 from repro.core.ode import odeint
 
 Pytree = Any
@@ -204,6 +204,30 @@ def make_autonomous_twin(state_dim: int, hidden: int = 64,
     """Lorenz96-style twin: dy/dt = MLP(y) (no external stimulation)."""
     sizes = (state_dim,) + (hidden,) * n_hidden_layers + (state_dim,)
     field = MLPVectorField(sizes=sizes, drive=None)
+    node = NeuralODE(field=field, method=method, gradient=gradient,
+                     steps_per_interval=steps_per_interval, backend=backend)
+    return DigitalTwin(field=field, node=node, state_dim=state_dim)
+
+
+def make_cde_twin(state_dim: int = 90, control_channels: int = 21,
+                  hidden: int = 40, n_hidden_layers: int = 4,
+                  control: Optional[Callable] = None, method: str = "rk4",
+                  gradient: str = "adjoint", steps_per_interval: int = 1,
+                  backend: Optional[Backend] = None) -> DigitalTwin:
+    """Neural-CDE twin: dz/dt = tanh(MLP(z)) · dX/dt, the MLP
+    D -> H -> ... -> H -> D·Du with ``n_hidden_layers`` hidden widths and
+    its output read as a (D, Du) matrix (:class:`CDEVectorField`).
+
+    ``control(t) -> (Du,)`` is the control's derivative dX/dt; a fleet
+    passes ``TwinFleet(twin, drive_family=lambda t, theta: ...)`` so that
+    each twin has its own.  The defaults are the Speech Commands widths of
+    Kidger et al. (arXiv:2005.08926): hidden channels 90, hidden-hidden 40,
+    4 hidden layers, 20 MFCC channels plus time.
+    """
+    sizes = ((state_dim,) + (hidden,) * n_hidden_layers
+             + (state_dim * control_channels,))
+    field = CDEVectorField(sizes=sizes, control_channels=control_channels,
+                           drive=control)
     node = NeuralODE(field=field, method=method, gradient=gradient,
                      steps_per_interval=steps_per_interval, backend=backend)
     return DigitalTwin(field=field, node=node, state_dim=state_dim)

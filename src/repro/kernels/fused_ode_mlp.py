@@ -10,6 +10,10 @@ the trajectory out.  A step-by-step XLA implementation would re-read the
 weights from HBM every f-eval and write every intermediate state back; at
 the paper's sizes that makes the solve HBM-latency-bound.
 
+The vector field is a parameter of the in-kernel step: :class:`MLPField`
+(the ReLU MLP dy/dt = MLP([u, y])) or :class:`CDEField` (the neural CDE
+dz/dt = tanh(MLP(z)) · dX/dt, its control derivative the drive).
+
 Grid: (batch tiles, time chunks); weights broadcast to every cell.  Time
 is the minor grid dimension, so all chunks of one batch tile run back to
 back and the integration state is carried across chunks in a VMEM scratch
@@ -74,6 +78,7 @@ bitwise parity.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Callable, NamedTuple, Sequence
 
@@ -248,9 +253,12 @@ def plan_time_chunk(T: int, bt: int, D: int, du: int, per_tile_drive: bool,
                     weights: Sequence[jax.Array], biases: Sequence[jax.Array],
                     vmem_budget_bytes: int,
                     time_chunk: int | None = None,
-                    precision: str = "f32") -> ChunkPlan:
+                    precision: str = "f32", field=None) -> ChunkPlan:
     """Pick the largest time chunk C whose per-cell working set fits the
     VMEM budget (or honour an explicit ``time_chunk`` override).
+    ``weights`` are the kernel's staged operands (``field.stage``);
+    ``field`` (default: the MLP of those weights) sets the activation
+    slack.
 
     Per-cell bytes, every buffer counted tiled (:func:`vmem_tile_bytes`)
     and at its policy dtype; pipelined blocks count twice (Pallas
@@ -260,18 +268,20 @@ def plan_time_chunk(T: int, bt: int, D: int, du: int, per_tile_drive: bool,
       2 · (C, bt, D) out slab             at the storage dtype
       2 · drive block                     f32 (:func:`drive_block_shape`)
       (bt, D) carry scratch               at the carry dtype
-      RK4 activation slack                :func:`_rk4_activation_bytes`
+      RK4 activation slack                ``field.activation_bytes``
 
     bf16 storage halves the out slab, so the planned chunk is ~2x the
     f32 one at a fixed budget (the weights-must-fit threshold moves by
     about the same factor).
     """
     store, _, acc, carry = precision_dtypes(resolve_precision(precision))
+    if field is None:
+        field = MLPField(len(weights))
     fixed = (2 * (vmem_tile_bytes((bt, D), jnp.float32)
                   + sum(vmem_tile_bytes(w.shape, store) for w in weights)
                   + sum(vmem_tile_bytes(b.shape, store) for b in biases))
              + vmem_tile_bytes((bt, D), carry)
-             + _rk4_activation_bytes(bt, D, weights, acc))
+             + field.activation_bytes(bt, D, weights, acc))
 
     def need(C):
         return fixed + 2 * (
@@ -283,9 +293,98 @@ def plan_time_chunk(T: int, bt: int, D: int, du: int, per_tile_drive: bool,
                                  "fused kernel")
 
 
-def make_rk4_step(num_layers: int, dt: float, drive_dim: int, bt: int,
+@dataclasses.dataclass(frozen=True)
+class MLPField:
+    """The in-kernel ReLU MLP field dy/dt = MLP([u, y]), or MLP(y) where
+    the drive has no channels: ReLU between layers, none on the output.
+    The fused backward (:mod:`repro.kernels.fused_ode_mlp_bwd`) replays
+    and transposes it."""
+    num_layers: int
+
+    differentiable = True
+
+    def stage(self, weights, biases):
+        """The kernel's operands: the layers as they are."""
+        return list(weights), list(biases)
+
+    def activation_bytes(self, bt: int, D: int, weights, acc_dtype) -> int:
+        return _rk4_activation_bytes(bt, D, weights, acc_dtype)
+
+    def __call__(self, u, y, ws, bs, linear):
+        x = y if u is None else jnp.concatenate([u, y], axis=-1)
+        for i in range(self.num_layers):
+            x = linear(x, ws[i], bs[i])
+            if i < self.num_layers - 1:
+                x = jnp.maximum(x, 0.0)
+        return x
+
+
+@dataclasses.dataclass(frozen=True)
+class CDEField:
+    """The in-kernel neural CDE field dz/dt = f(z) · dX/dt (Kidger et
+    al. 2020): f(z) = tanh(MLP(z)), ReLU between the MLP's layers, its
+    (D·Du)-wide output read as a (D, Du) matrix and contracted with the
+    twin's Du-channel control derivative, which is the kernel's drive.
+
+    The MLP's matmuls follow the precision policy; tanh and the
+    contraction run at f32.  The output layer is staged channel-major,
+    one 128-lane block per channel (:meth:`stage`), so the contraction
+    is Du lane-aligned slices of the tanh tile, each scaled by one
+    control column and summed on the VPU: no reshape of the tile crosses
+    lanes.  No fused backward exists for it."""
+    num_layers: int
+    state_dim: int
+    channels: int
+
+    differentiable = False
+
+    @property
+    def block(self) -> int:
+        """Lanes of one channel's block of the staged output layer."""
+        return -(-self.state_dim // 128) * 128
+
+    def stage(self, weights, biases):
+        """The kernel's operands: the hidden layers as they are; the output
+        layer's column i·Du + c (entry (i, c) of the source's (D, Du)
+        view) moved to c·block + i, the padding columns zero (tanh(0) = 0
+        adds nothing)."""
+        D, Du, P = self.state_dim, self.channels, self.block
+        w, b = weights[-1], biases[-1]
+        if w.shape[1] != D * Du:
+            raise ValueError(f"CDEField: the output layer has {w.shape[1]} "
+                             f"columns, the field needs D·Du = {D * Du}")
+        w = jnp.transpose(w.reshape(w.shape[0], D, Du), (0, 2, 1))
+        w = jnp.pad(w, ((0, 0), (0, 0), (0, P - D))).reshape(-1, Du * P)
+        b = jnp.pad(b.reshape(D, Du).T, ((0, 0), (0, P - D))).reshape(Du * P)
+        return list(weights[:-1]) + [w], list(biases[:-1]) + [b]
+
+    def activation_bytes(self, bt: int, D: int, weights, acc_dtype) -> int:
+        """The MLP's (as :func:`_rk4_activation_bytes`) and three f32
+        (bt, Du·block) tiles: the output layer's sum, its tanh and one
+        scaled block sum in flight."""
+        f32 = jnp.float32
+        return (_rk4_activation_bytes(bt, D, weights, acc_dtype)
+                + 3 * vmem_tile_bytes((bt, weights[-1].shape[1]), f32))
+
+    def __call__(self, u, z, ws, bs, linear):
+        x = z
+        for i in range(self.num_layers):
+            x = linear(x, ws[i], bs[i])
+            if i < self.num_layers - 1:
+                x = jnp.maximum(x, 0.0)
+        g = jnp.tanh(x.astype(jnp.float32))
+        u = u.astype(jnp.float32)
+        P = self.block
+        dz = g[:, :P] * u[:, 0:1]
+        for c in range(1, self.channels):
+            dz = dz + g[:, c * P:(c + 1) * P] * u[:, c:c + 1]
+        return dz[:, :self.state_dim]
+
+
+def make_rk4_step(field, dt: float, drive_dim: int, bt: int,
                   per_tile_drive: bool, precision: str = "f32"):
-    """One in-kernel RK4 step ``step(y, u0, um, u1, ws, bs) -> y_next``.
+    """One in-kernel RK4 step ``step(y, u0, um, u1, ws, bs) -> y_next``
+    of the in-kernel ``field`` (:class:`MLPField`, :class:`CDEField`).
 
     SHARED between the forward kernel and the backward kernel's
     checkpoint replay + step VJP (:mod:`repro.kernels.fused_ode_mlp_bwd`)
@@ -302,28 +401,22 @@ def make_rk4_step(num_layers: int, dt: float, drive_dim: int, bt: int,
     # asks for full f32 products (interpret mode computes them anyway)
     dot_precision = lax.Precision.HIGHEST if compute == jnp.float32 else None
 
-    def mlp(x, ws, bs):
-        for i in range(num_layers):
-            # the MXU accumulates at f32 (Mosaic refuses a bf16
-            # accumulator); pure "bf16" rounds the sum once afterwards
-            x = jnp.dot(x.astype(compute), ws[i], precision=dot_precision,
-                        preferred_element_type=jnp.float32).astype(acc)
-            # widen BEFORE broadcasting: the VJP of a width-1 bias sums
-            # to a scalar, and Mosaic extracts only 32-bit scalars
-            x = x + bs[i].astype(acc)[None, :]
-            if i < num_layers - 1:
-                x = jnp.maximum(x, 0.0)
-        return x.astype(carry)
+    def linear(x, w, b):
+        # the MXU accumulates at f32 (Mosaic refuses a bf16
+        # accumulator); pure "bf16" rounds the sum once afterwards
+        x = jnp.dot(x.astype(compute), w, precision=dot_precision,
+                    preferred_element_type=jnp.float32).astype(acc)
+        # widen BEFORE broadcasting: the VJP of a width-1 bias sums
+        # to a scalar, and Mosaic extracts only 32-bit scalars
+        return x + b.astype(acc)[None, :]
 
     def f(u_row, y, ws, bs):
+        u = None
         if drive_dim > 0:
             # u_row: (drive_dim,) broadcast, or (bt, drive_dim) per-twin
             u = (u_row if per_tile_drive
-                 else jnp.broadcast_to(u_row, (bt, drive_dim)))
-            inp = jnp.concatenate([u.astype(carry), y], axis=-1)
-        else:
-            inp = y
-        return mlp(inp, ws, bs)
+                 else jnp.broadcast_to(u_row, (bt, drive_dim))).astype(carry)
+        return field(u, y, ws, bs, linear).astype(carry)
 
     def step(y, u0, um, u1, ws, bs):
         k1 = f(u0, y, ws, bs)
@@ -379,11 +472,12 @@ def drive_window(u_half: jax.Array, start_step: int,
     return u_half[:, lo:hi] if axis == 1 else u_half[lo:hi]
 
 
-def _make_kernel(num_layers: int, C: int, dt: float, drive_dim: int,
+def _make_kernel(field, C: int, dt: float, drive_dim: int,
                  bt: int, per_tile_drive: bool = False,
                  precision: str = "f32"):
     store, _, _, carry = precision_dtypes(resolve_precision(precision))
-    step = make_rk4_step(num_layers, dt, drive_dim, bt, per_tile_drive,
+    num_layers = field.num_layers
+    step = make_rk4_step(field, dt, drive_dim, bt, per_tile_drive,
                          precision)
 
     def kernel(*refs):
@@ -457,6 +551,27 @@ def chunked_drive_operand(u_half: jax.Array, T: int, C: int, NC: int,
                               index)
 
 
+def rollout_plan(B: int, D: int, u_shape: Sequence[int],
+                 weights: Sequence[jax.Array], biases: Sequence[jax.Array],
+                 *, batch_tile: int = 64, time_chunk: int | None = None,
+                 vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET,
+                 precision: str | None = None, field=None) -> ChunkPlan:
+    """The plan :func:`fused_node_rollout` takes for a (B, D) batch, a
+    half-step drive of shape ``u_shape`` and the field's layers in
+    source order, from their shapes alone: a caller counts the kernel's
+    time chunks without tracing a solve."""
+    if field is None:
+        field = MLPField(len(weights))
+    weights, biases = jax.eval_shape(field.stage, list(weights),
+                                     list(biases))
+    du = u_shape[-1]
+    return plan_time_chunk((u_shape[-2] - 1) // 2, min(batch_tile, B), D,
+                           du, len(u_shape) == 3 and du > 0, weights, biases,
+                           vmem_budget_bytes, time_chunk,
+                           precision=resolve_precision(precision),
+                           field=field)
+
+
 def fused_node_rollout(
     y0: jax.Array,                    # (B, D) float
     u_half: jax.Array,                # (2T+1, Du) shared or (B, 2T+1, Du)
@@ -469,9 +584,15 @@ def fused_node_rollout(
     interpret: bool | None = None,
     vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET,
     precision: str | None = None,
+    field=None,
 ) -> jax.Array:
     """Full-trajectory RK4 solve; returns (T+1, B, D) at the policy's
     storage dtype.  See module doc.
+
+    ``field`` is the in-kernel vector field (:class:`MLPField` of the
+    given layers by default, or :class:`CDEField`); ``weights`` and
+    ``biases`` are its layers as the source orders them, which
+    ``field.stage`` lays out for the kernel.
 
     ``u_half`` is the drive sampled at RK4 half-steps: (2T+1, Du) shared
     by the whole batch, or (B, 2T+1, Du) with one stimulus per batch
@@ -495,6 +616,14 @@ def fused_node_rollout(
     for li, (w, b) in enumerate(zip(weights, biases)):
         _require_float(f"weights[{li}]", w, precision)
         _require_float(f"biases[{li}]", b, precision)
+    if field is None:
+        field = MLPField(len(weights))
+    plan = rollout_plan(*y0.shape, u_half.shape, weights, biases,
+                        batch_tile=batch_tile, time_chunk=time_chunk,
+                        vmem_budget_bytes=vmem_budget_bytes,
+                        precision=precision, field=field)
+    C, NC = plan.time_chunk, plan.num_chunks
+    weights, biases = field.stage(weights, biases)
     weights = [w.astype(store) for w in weights]
     biases = [b.astype(store) for b in biases]
     u_half = u_half.astype(DRIVE_DTYPE)
@@ -508,17 +637,11 @@ def fused_node_rollout(
         per_tile_drive, u_half = False, u_half[0]
     T = (u_half.shape[1 if per_tile_drive else 0] - 1) // 2
     du = u_half.shape[-1]
-    L = len(weights)
     bt = min(batch_tile, B)
     if B % bt:
         raise ValueError(f"batch {B} not divisible by tile {bt}")
 
-    plan = plan_time_chunk(T, bt, D, du, per_tile_drive, weights, biases,
-                           vmem_budget_bytes, time_chunk,
-                           precision=precision)
-    C, NC = plan.time_chunk, plan.num_chunks
-
-    kernel = _make_kernel(L, C, float(dt), du, bt, per_tile_drive,
+    kernel = _make_kernel(field, C, float(dt), du, bt, per_tile_drive,
                           precision)
 
     grid = (B // bt, NC)                 # time minor: chunks run in order

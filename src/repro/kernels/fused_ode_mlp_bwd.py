@@ -66,7 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET, DRIVE_DTYPE,
-                                         ChunkPlan,
+                                         ChunkPlan, MLPField,
                                          _rk4_activation_bytes,
                                          chunked_drive_operand,
                                          drive_block_shape,
@@ -122,7 +122,8 @@ def _make_bwd_kernel(num_layers: int, C: int, dt: float,
     # THE step of the forward kernel — shared so the checkpoint replay
     # recomputes bit-identical states and the VJP transposes the exact
     # update the forward applied (same precision policy included)
-    rk4 = make_rk4_step(L, dt, drive_dim, bt, per_tile_drive, precision)
+    rk4 = make_rk4_step(MLPField(L), dt, drive_dim, bt, per_tile_drive,
+                        precision)
 
     def kernel(*refs):
         yb_ref, u_ref, g_ref = refs[0], refs[1], refs[2]
@@ -295,15 +296,20 @@ def fused_node_rollout_bwd(
 # The differentiable rollout: custom VJP over (y0, u_half, weights, biases)
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def fused_node_rollout_vjp(y0, u_half, weights, biases, dt,
                            batch_tile=64, time_chunk=None, interpret=None,
                            vmem_budget_bytes=DEFAULT_VMEM_BUDGET,
-                           precision=None):
+                           precision=None, field=None):
     """:func:`fused_node_rollout` with gradients that never leave the
     fused substrate: forward AND backward are whole-chunk Pallas kernels,
     weights pinned in VMEM both ways.  Differentiable in ``y0``,
     ``weights`` and ``biases``; the drive gets a zero cotangent.
+
+    ``field`` (default: the MLP of ``weights``) is the in-kernel vector
+    field; one without a fused backward (``field.differentiable`` false,
+    the neural CDE) runs its forward here and raises
+    ``NotImplementedError`` when differentiated.
 
     ``precision`` is a nondiff static: the forward casts the operands to
     the policy's storage dtype internally, and the backward returns
@@ -315,14 +321,14 @@ def fused_node_rollout_vjp(y0, u_half, weights, biases, dt,
     ``jax.grad`` are bitwise identical even under the bf16 policies
     (where chunk boundaries are rounding points).
     """
+    if field is None or field.differentiable:
+        time_chunk = _shared_chunk(y0, u_half, weights, biases, batch_tile,
+                                   time_chunk, vmem_budget_bytes, precision)
     return fused_node_rollout(y0, u_half, weights, biases, dt,
-                              batch_tile=batch_tile,
-                              time_chunk=_shared_chunk(
-                                  y0, u_half, weights, biases, batch_tile,
-                                  time_chunk, vmem_budget_bytes, precision),
+                              batch_tile=batch_tile, time_chunk=time_chunk,
                               interpret=interpret,
                               vmem_budget_bytes=vmem_budget_bytes,
-                              precision=precision)
+                              precision=precision, field=field)
 
 
 def _shared_chunk(y0, u_half, weights, biases, batch_tile, time_chunk,
@@ -349,7 +355,12 @@ def _shared_chunk(y0, u_half, weights, biases, batch_tile, time_chunk,
 
 
 def _rollout_fwd(y0, u_half, weights, biases, dt, batch_tile, time_chunk,
-                 interpret, vmem_budget_bytes, precision):
+                 interpret, vmem_budget_bytes, precision, field):
+    if field is not None and not field.differentiable:
+        raise NotImplementedError(
+            f"the fused substrate has no backward for {type(field).__name__}"
+            f": differentiate this field on the digital backend, or pass "
+            f"gradient='stopgrad' to serve it")
     traj = fused_node_rollout(y0, u_half, weights, biases, dt,
                               batch_tile=batch_tile,
                               time_chunk=_shared_chunk(
@@ -369,7 +380,8 @@ def _rollout_fwd(y0, u_half, weights, biases, dt, batch_tile, time_chunk,
 
 
 def _rollout_bwd(dt, batch_tile, time_chunk, interpret, vmem_budget_bytes,
-                 precision, res, g):
+                 precision, field, res, g):
+    del field               # only the MLP gets here (``_rollout_fwd``)
     u_half, weights, biases, traj, y0_marker = res
     precision = resolve_precision(precision)
     store, _, _, _ = precision_dtypes(precision)

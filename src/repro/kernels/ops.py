@@ -46,6 +46,7 @@ def fused_node_rollout(params: Sequence[dict], y0: jax.Array,
                        vmem_budget_bytes: int = DEFAULT_VMEM_BUDGET,
                        gradient: str = "fused_vjp",
                        precision: str | None = None,
+                       field=None,
                        ) -> jax.Array:
     """Solve the twin's neural ODE with the weights-stationary kernel.
 
@@ -86,6 +87,10 @@ def fused_node_rollout(params: Sequence[dict], y0: jax.Array,
         always stay f32; the error model is documented in
         ``docs/kernels.md``.  Non-floating inputs raise a ``ValueError``
         naming the offending input.
+      field: the in-kernel vector field — ``None`` for the ReLU MLP of
+        ``params``, or a ``fused_ode_mlp.CDEField`` (forward only: its
+        ``"fused_vjp"`` solve raises ``NotImplementedError`` when
+        differentiated).
 
     Returns:
       The (T+1, B, D) trajectory (y0 prepended), at the policy's
@@ -108,7 +113,7 @@ def fused_node_rollout(params: Sequence[dict], y0: jax.Array,
         return fused_node_rollout_vjp(y0, u_half, weights, biases,
                                       float(dt), batch_tile, time_chunk,
                                       interpret, vmem_budget_bytes,
-                                      precision)
+                                      precision, field)
     if gradient == "stopgrad":
         out = _fused_pallas(lax.stop_gradient(y0),
                             lax.stop_gradient(u_half),
@@ -118,7 +123,7 @@ def fused_node_rollout(params: Sequence[dict], y0: jax.Array,
                             batch_tile=batch_tile, time_chunk=time_chunk,
                             interpret=interpret,
                             vmem_budget_bytes=vmem_budget_bytes,
-                            precision=precision)
+                            precision=precision, field=field)
         return lax.stop_gradient(out)
     raise ValueError(
         f"unknown gradient mode {gradient!r}; have 'fused_vjp', 'stopgrad'")

@@ -61,6 +61,55 @@ def fused_node_rollout_ref(y0: jax.Array, u_half: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# neural CDE field and its RK4 rollout
+# ---------------------------------------------------------------------------
+
+def cde_field_ref(weights: list[jax.Array], biases: list[jax.Array],
+                  z: jax.Array, dxdt: jax.Array) -> jax.Array:
+    """The neural CDE field of Kidger et al. (arXiv:2005.08926, the
+    ``FinalTanh`` field of ``experiments/speech_commands.py``): Linear,
+    ReLU, ..., Linear, tanh on the (B, D) state, the (B, D·Du) output read
+    row-major as (B, D, Du) and contracted with the (B, Du) control
+    derivative."""
+    g = jnp.tanh(mlp_fwd(weights, biases, z))
+    g = g.reshape(z.shape[0], z.shape[1], dxdt.shape[-1])
+    return jnp.einsum("bic,bc->bi", g, dxdt)
+
+
+def cde_rollout_ref(z0: jax.Array, u_half: jax.Array,
+                    weights: list[jax.Array], biases: list[jax.Array],
+                    dt: float) -> jax.Array:
+    """RK4 rollout of dz/dt = f(z) · dX/dt in float32 at ``highest``
+    matmul precision: (B, D) states and (B, 2T+1, Du) control derivatives
+    on the half-step grid -> (T+1, B, D).
+
+    Departures from the source: the control is whatever path the caller
+    samples (a seeded analytic path, not a spline of recorded audio); the
+    solver is fixed-step RK4, one step per observation interval, not an
+    adaptive one; the initial map z0 = ζ(X(0)) and the readout are not
+    part of the rollout (the twin serves z).
+    """
+    u_half = jnp.transpose(u_half, (1, 0, 2))         # (2T+1, B, Du)
+    T = (u_half.shape[0] - 1) // 2
+
+    def f(u, z):
+        return cde_field_ref(weights, biases, z, u)
+
+    def step(z, t):
+        u0, um, u1 = u_half[2 * t], u_half[2 * t + 1], u_half[2 * t + 2]
+        k1 = f(u0, z)
+        k2 = f(um, z + dt / 2 * k1)
+        k3 = f(um, z + dt / 2 * k2)
+        k4 = f(u1, z + dt * k3)
+        z = z + dt / 6 * (k1 + 2 * k2 + 2 * k3 + k4)
+        return z, z
+
+    with jax.default_matmul_precision("highest"):
+        _, zs = lax.scan(step, z0, jnp.arange(T))
+    return jnp.concatenate([z0[None], zs], axis=0)
+
+
+# ---------------------------------------------------------------------------
 # crossbar differential-pair VMM
 # ---------------------------------------------------------------------------
 

@@ -521,7 +521,10 @@ class StreamStats:
     trajectory copy, probe reads); eviction reads are
     ``StoreStats.evict_reads``.  ``started`` and ``queue_wait_s`` count a
     request once, at its first assembly: ``queue_wait_s`` sums
-    ``now - t_arrival`` on the caller's clock (``submit``/``pump``)."""
+    ``now - t_arrival`` on the caller's clock (``submit``/``pump``).
+    ``time_chunks`` sums the digital fused kernel's time chunks over its
+    solves and ``drive_bytes`` the bytes of the drive windows assembled
+    for the fused tiers (both 0 on the digital and analogue tiers)."""
     enqueued: int = 0
     served: int = 0
     failed: int = 0
@@ -535,6 +538,8 @@ class StreamStats:
     host_syncs: int = 0      # blocking device->host reads
     started: int = 0         # requests assembled for the first time
     queue_wait_s: float = 0.0   # their summed wait from arrival
+    time_chunks: int = 0     # fused-kernel time chunks, summed over solves
+    drive_bytes: int = 0     # bytes of drive window assembled for them
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -698,6 +703,7 @@ class StreamingFleetServer:
             self._backends.append(backend)
             self._states.append(backend.program(node.field, params))
         self._window_fns: dict = {}            # (tier_idx, H) -> jit fn
+        self._time_chunks: dict = {}           # (tier_idx, H, B) -> chunks
         self._queue: list = []                 # FIFO of StreamRequest
         self._partial: dict = {}               # seq -> list of row blocks
         self._seq = 0
@@ -912,6 +918,11 @@ class StreamingFleetServer:
                                             starts, drive_family, thetas)
                 if uh.ndim == 2 and uh.shape[-1] > 0:
                     uh = jnp.broadcast_to(uh, (ys.shape[0],) + uh.shape)
+            key = (tier_idx, H, ys.shape[0])
+            if key not in self._time_chunks:
+                self._time_chunks[key] = backend.time_chunks(state, ys, uh)
+            self.stream_stats.time_chunks += self._time_chunks[key]
+            self.stream_stats.drive_bytes += uh.size * uh.dtype.itemsize
             return fn(ys, uh)
         with jax.profiler.TraceAnnotation("solve.drive"):
             tss = ops.window_times(self.t0, self.dt, H, starts)

@@ -19,13 +19,15 @@ import pytest
 from repro.kernels import ops
 from repro.kernels.crossbar_vmm import crossbar_matmul
 from repro.kernels.fused_analogue import fused_analogue_rollout
-from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET,
+from repro.kernels.fused_ode_mlp import (DEFAULT_VMEM_BUDGET, CDEField,
                                          fused_node_rollout)
 from repro.kernels.fused_ode_mlp_bwd import fused_node_rollout_vjp
 
 PRECISIONS = ["f32", "bf16_f32acc"]
 L96 = dict(sizes=(6, 64, 64, 6), dt=0.0025, T=200)       # FLEET widths
 HP = dict(sizes=(2, 14, 14, 1), dt=1e-3, T=500)          # HP twin widths
+# the neural CDE at its Speech Commands widths, one 160-step clip
+NCDE = dict(sizes=(90, 40, 40, 40, 40, 1890), dt=1.0, T=160, Du=21)
 
 
 @pytest.fixture(scope="module")
@@ -84,6 +86,21 @@ def test_fused_forward_compiles(one_chip, case, precision):
         lambda y0, u, w, b: fused_node_rollout(
             y0, u, w, b, cfg["dt"], interpret=False, precision=precision),
         _spec(one_chip, (B, D)), _spec(one_chip, drive), w, b)
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_fused_cde_forward_compiles(one_chip, precision):
+    """The CDE field in the forward kernel over a 1024-twin fleet with a
+    per-twin 21-channel control: the (bt, 2688) tanh tile, its lane-block
+    contraction and the chunked control slab."""
+    w, b = _mlp(one_chip, NCDE["sizes"])
+    field = CDEField(len(w), NCDE["sizes"][0], NCDE["Du"])
+    _compiles_to_kernel(
+        lambda y0, u, w, b: fused_node_rollout(
+            y0, u, w, b, NCDE["dt"], interpret=False, precision=precision,
+            field=field),
+        _spec(one_chip, (1024, NCDE["sizes"][0])),
+        _spec(one_chip, (1024, 2 * NCDE["T"] + 1, NCDE["Du"])), w, b)
 
 
 @pytest.mark.parametrize("precision", PRECISIONS)
